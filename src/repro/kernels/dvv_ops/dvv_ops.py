@@ -1,18 +1,21 @@
-"""Pallas TPU kernel: batched dotted-version-vector dominance.
+"""Pallas TPU kernels: batched dotted-version-vector dominance.
 
 Anti-entropy between replica nodes compares the clock sets of every
 transferred key (paper §4.1); at production scale that is millions of
 ``leq`` evaluations per round.  The array encoding (core/batched.py) turns
 one comparison into a handful of int32 vector ops over the replica
-universe — ideal VPU work.  This kernel tiles the key dimension into VMEM
-blocks; the replica dim is padded to the 128-wide lane axis.
+universe — ideal VPU work.
 
-Design notes (TPU adaptation, DESIGN.md §3):
+Layout (TPU adaptation, DESIGN.md §3.2): keys ride the 128-wide lane axis,
+blocked by the grid; the replica universe rides the sublane axis, padded to
+a multiple of 8.  Per-clock scalars (dot id, dot counter, valid, output)
+are ``[K, keys]`` rows, so no operand wastes lanes on padding.
+
   * the per-clock dot lookup ``vy[ix]`` is a dynamic gather in the jnp
-    reference; here it is a masked lane-sum (`where(lane==ix, vy, 0)`),
-    which maps to VPU selects + a lane reduction instead of a gather;
-  * all scalars ride as [N, 1] columns so every op stays 2-D (sublane ×
-    lane), the layout the TPU vector unit wants.
+    reference; here it is a masked sublane-sum (`where(sub == ix, vy, 0)`),
+    which maps to VPU selects + a sublane reduction instead of a gather;
+  * every predicate is pure boolean algebra over comparisons — Mosaic
+    refuses a boolean ``where`` against a Python literal.
 """
 from __future__ import annotations
 
@@ -22,153 +25,160 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...core.batched import merge_context
+
 NO_DOT = -1
 LANES = 128
-DEFAULT_BLOCK = 512
+SUBLANES = 8
+#: Most keys (lanes) per grid step.
+MAX_BLOCK = 512
+#: VMEM budget for one grid step's clock block ``int32[K, R_pad, block]``;
+#: the key block shrinks (never below one lane tile) to stay inside it, so
+#: K = 16 at R_pad = 128 still fits the default scoped VMEM double-buffered.
+CLOCK_BLOCK_BYTES = 512 * 1024
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def key_block(n: int, k: int, r_pad: int) -> int:
+    """Keys per grid step: a multiple of 128, at most ``MAX_BLOCK``, and
+    small enough that the ``[k, r_pad, block]`` clock block fits
+    ``CLOCK_BLOCK_BYTES``."""
+    fit = CLOCK_BLOCK_BYTES // (4 * k * r_pad) // LANES * LANES
+    return max(LANES, min(MAX_BLOCK, fit, _round_up(n, LANES)))
+
+
+def _covers(sub, vx, ix, nx, vy, iy, ny):
+    """history(x) ⊆ history(y), batched → bool[..., 1, B].
+
+    vx, vy: int32[..., R_pad, B] (replicas on sublanes, keys on lanes);
+    ix/nx/iy/ny: int32[..., 1, B]; ``sub`` is the replica-axis iota.
+    """
+    # range coverage: 1..vx[r] ⊆ 1..vy[r] ∪ {ny at iy}
+    dot_extends = (sub == iy) & (vx == ny) & (vx == vy + 1)
+    range_bad = jnp.max(jnp.where((vx <= vy) | dot_extends, 0, 1),
+                        axis=-2, keepdims=True)
+    # dot coverage: no dot, or nx ≤ vy[ix], or (ix == iy ∧ nx == ny)
+    vy_at_ix = jnp.sum(jnp.where(sub == ix, vy, 0), axis=-2, keepdims=True)
+    dot_ok = (nx <= vy_at_ix) | ((iy == ix) & (nx == ny))
+    return (range_bad == 0) & ((ix == NO_DOT) | dot_ok)
 
 
 def _leq_kernel(vx_ref, ix_ref, nx_ref, vy_ref, iy_ref, ny_ref, out_ref):
-    vx = vx_ref[...]                       # [BN, R]
-    vy = vy_ref[...]
-    ix = ix_ref[...]                       # [BN, 1]
-    nx = nx_ref[...]
-    iy = iy_ref[...]
-    ny = ny_ref[...]
+    sub = jax.lax.broadcasted_iota(jnp.int32, vx_ref.shape, 0)
+    ok = _covers(sub, vx_ref[...], ix_ref[...], nx_ref[...],
+                 vy_ref[...], iy_ref[...], ny_ref[...])
+    out_ref[...] = jnp.where(ok, 1, 0)
 
-    BN, R = vx.shape
-    lane = jax.lax.broadcasted_iota(jnp.int32, (BN, R), 1)
 
-    # range coverage: 1..vx[r] ⊆ 1..vy[r] ∪ {ny at iy}
-    dot_extends = (lane == iy) & (vx == ny) & (vx == vy + 1)
-    range_ok = jnp.all((vx <= vy) | dot_extends, axis=1, keepdims=True)
-
-    # dot coverage: nx ≤ vy[ix]  ∨  (ix == iy ∧ nx == ny)
-    vy_at_ix = jnp.sum(jnp.where(lane == ix, vy, 0), axis=1, keepdims=True)
-    dot_ok = (nx <= vy_at_ix) | ((iy == ix) & (nx == ny))
-    has_dot = ix != NO_DOT
-    ok = range_ok & jnp.where(has_dot, dot_ok, True)
-    out_ref[...] = ok.astype(jnp.int8)
+def _rot(a, d):
+    """Rotate the slot (leading) axis: ``_rot(a, d)[k] = a[(k + d) % K]``."""
+    return jnp.concatenate([a[d:], a[:d]], axis=0) if d else a
 
 
 def _sync_mask_kernel(vv_ref, id_ref, n_ref, valid_ref, out_ref):
     """Fused pairwise dominance + survival for one block of keys.
 
-    vv_ref    : int32[K, BN, Rp]  — K version slots per key, keys on sublanes
-    id/n/valid: int32[K, BN, 1]
-    out_ref   : int8 [K, BN, 1]   — survival mask
+    vv_ref    : int32[K, R_pad, B]
+    id/n/valid: int32[K, B]
+    out_ref   : int32[K, B]  — survival mask
 
-    The K axis is a *static* Python loop (K = max versions per key, small);
-    every op inside is a 2-D [BN, Rp] VPU op.  Dominance of x by y is the
-    same masked-lane-sum formulation as ``_leq_kernel``; survival folds the
-    K×K sweep into one kernel so bulk anti-entropy is a single launch.
+    Slot x meets slot (x+d) mod K for every static shift d = 1..K−1, all K
+    slots at once, so the body is K−1 batched ``_covers`` calls (code size
+    linear in K; work is still the K(K−1) pairs).  Of two equal clocks the
+    lower slot survives.
     """
-    K, BN, Rp = vv_ref.shape
-    lane = jax.lax.broadcasted_iota(jnp.int32, (BN, Rp), 1)
-
-    def leq(vx, ix, nx, vy, iy, ny):
-        dot_extends = (lane == iy) & (vx == ny) & (vx == vy + 1)
-        range_ok = jnp.all((vx <= vy) | dot_extends, axis=1, keepdims=True)
-        vy_at_ix = jnp.sum(jnp.where(lane == ix, vy, 0), axis=1,
-                           keepdims=True)
-        dot_ok = (nx <= vy_at_ix) | ((iy == ix) & (nx == ny))
-        return range_ok & jnp.where(ix != NO_DOT, dot_ok, True)
-
-    for xk in range(K):
-        vx, ix, nx = vv_ref[xk], id_ref[xk], n_ref[xk]
-        x_valid = valid_ref[xk] != 0
-        dominated = jnp.zeros((BN, 1), dtype=jnp.bool_)
-        for yk in range(K):
-            if yk == xk:
-                continue
-            vy, iy, ny = vv_ref[yk], id_ref[yk], n_ref[yk]
-            y_valid = valid_ref[yk] != 0
-            le = leq(vx, ix, nx, vy, iy, ny)
-            ge = leq(vy, iy, ny, vx, ix, nx)
-            kill = le & ~ge                       # strictly dominated
-            if yk < xk:
-                kill = kill | (le & ge)           # duplicate: keep earliest
-            dominated = dominated | (kill & y_valid)
-        out_ref[xk] = (x_valid & ~dominated).astype(jnp.int8)
+    K, Rp, B = vv_ref.shape
+    vv = vv_ref[...]
+    ids, ns, valid = (r[...].reshape(K, 1, B)
+                      for r in (id_ref, n_ref, valid_ref))
+    sub = jax.lax.broadcasted_iota(jnp.int32, (K, Rp, B), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (K, 1, B), 0)
+    # le[d][x]: history(x) ⊆ history((x+d) mod K)
+    le = [None] + [_covers(sub, vv, ids, ns, _rot(vv, d), _rot(ids, d),
+                           _rot(ns, d)) for d in range(1, K)]
+    alive = valid != 0
+    for d in range(1, K):
+        ge = _rot(jnp.where(le[K - d], 1, 0), d) != 0    # (x+d) ⊆ x
+        wrapped = slot >= K - d                           # partner is lower
+        alive = alive & ~(le[d] & (~ge | wrapped) & (_rot(valid, d) != 0))
+    out_ref[...] = jnp.where(alive, 1, 0).reshape(K, B)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def dvv_sync_mask_pallas(vvs, dot_ids, dot_ns, valid, *,
-                         block: int = DEFAULT_BLOCK, interpret: bool = True):
+                         interpret: bool = True):
     """Which clocks of each key's combined set survive sync — one launch.
 
     vvs: int32[N, K, R]; dot_ids/dot_ns: int32[N, K]; valid: bool[N, K].
     Returns bool[N, K].  Semantics identical to ``core.batched.sync_mask``.
-
-    Layout: keys ride the sublane axis (N blocked), the replica universe is
-    padded to the 128-lane axis, and the K version slots become the leading
-    (static-loop) axis so every in-kernel op is a 2-D tile.
     """
     N, K, R = vvs.shape
     if N == 0 or K == 0:
         return jnp.zeros((N, K), bool)
-    block = min(block, max(8, N))
-    Rp = max(LANES, ((R + LANES - 1) // LANES) * LANES)
-    Np = ((N + block - 1) // block) * block
+    Rp = _round_up(max(R, 1), SUBLANES)
+    block = key_block(N, K, Rp)
+    Np = _round_up(N, block)
 
-    vvs_t = jnp.pad(vvs, ((0, Np - N), (0, 0), (0, Rp - R))
-                    ).transpose(1, 0, 2)                       # [K, Np, Rp]
+    vv_t = jnp.pad(vvs.astype(jnp.int32), ((0, Np - N), (0, 0), (0, Rp - R))
+                   ).transpose(1, 2, 0)                        # [K, Rp, Np]
 
-    def col(a, fill=0):
-        return jnp.pad(a, ((0, Np - N), (0, 0)),
-                       constant_values=fill).T[..., None]      # [K, Np, 1]
+    def rows(a, fill=0):
+        return jnp.pad(a.astype(jnp.int32), ((0, Np - N), (0, 0)),
+                       constant_values=fill).T                 # [K, Np]
 
-    args = (vvs_t, col(dot_ids, NO_DOT), col(dot_ns),
-            col(valid.astype(jnp.int32)))
-    grid = (Np // block,)
+    narrow = pl.BlockSpec((K, block), lambda i: (0, i))
     out = pl.pallas_call(
         _sync_mask_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((K, block, Rp), lambda i: (0, i, 0)),
-            pl.BlockSpec((K, block, 1), lambda i: (0, i, 0)),
-            pl.BlockSpec((K, block, 1), lambda i: (0, i, 0)),
-            pl.BlockSpec((K, block, 1), lambda i: (0, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((K, block, 1), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((K, Np, 1), jnp.int8),
+        grid=(Np // block,),
+        in_specs=[pl.BlockSpec((K, Rp, block), lambda i: (0, 0, i)),
+                  narrow, narrow, narrow],
+        out_specs=narrow,
+        out_shape=jax.ShapeDtypeStruct((K, Np), jnp.int32),
         interpret=interpret,
-    )(*args)
-    return out[:, :N, 0].T.astype(bool)
+    )(vv_t, rows(dot_ids, NO_DOT), rows(dot_ns), rows(valid))
+    return out[:, :N].T != 0
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def dvv_leq_pallas(vx, ix, nx, vy, iy, ny, *, block: int = DEFAULT_BLOCK,
-                   interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dvv_read_sweep_pallas(vvs, dot_ids, dot_ns, valid, *,
+                          interpret: bool = True):
+    """Survival mask and per-key §5.4 ceiling of the survivors, one
+    program: ``(bool[N, K], int32[N, R])``."""
+    mask = dvv_sync_mask_pallas(vvs, dot_ids, dot_ns, valid,
+                                interpret=interpret)
+    return mask, merge_context(vvs, dot_ids, dot_ns, mask)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dvv_leq_pallas(vx, ix, nx, vy, iy, ny, *, interpret: bool = True):
     """history(x_k) ⊆ history(y_k) for k in [N].
 
     vx, vy: int32[N, R]; ix/nx/iy/ny: int32[N].  Returns bool[N].
     """
     N, R = vx.shape
-    Rp = max(LANES, ((R + LANES - 1) // LANES) * LANES)
-    Np = ((N + block - 1) // block) * block
+    Rp = _round_up(max(R, 1), SUBLANES)
+    block = key_block(N, 1, Rp)
+    Np = _round_up(N, block)
 
-    def pad2(a, fill=0):
-        return jnp.pad(a, ((0, Np - N), (0, Rp - R)), constant_values=fill)
+    def clocks(a):
+        return jnp.pad(a.astype(jnp.int32), ((0, Np - N), (0, Rp - R))).T
 
-    def pad1(a, fill=0):
-        return jnp.pad(a, (0, Np - N), constant_values=fill)[:, None]
+    def row(a, fill=0):
+        return jnp.pad(a.astype(jnp.int32), (0, Np - N),
+                       constant_values=fill)[None, :]
 
-    args = (pad2(vx), pad1(ix, NO_DOT), pad1(nx), pad2(vy),
-            pad1(iy, NO_DOT), pad1(ny))
-    grid = (Np // block,)
+    wide = pl.BlockSpec((Rp, block), lambda i: (0, i))
+    narrow = pl.BlockSpec((1, block), lambda i: (0, i))
     out = pl.pallas_call(
         _leq_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block, Rp), lambda i: (i, 0)),
-            pl.BlockSpec((block, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block, Rp), lambda i: (i, 0)),
-            pl.BlockSpec((block, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Np, 1), jnp.int8),
+        grid=(Np // block,),
+        in_specs=[wide, narrow, narrow, wide, narrow, narrow],
+        out_specs=narrow,
+        out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
         interpret=interpret,
-    )(*args)
-    return out[:N, 0].astype(bool)
+    )(clocks(vx), row(ix, NO_DOT), row(nx), clocks(vy), row(iy, NO_DOT),
+      row(ny))
+    return out[0, :N] != 0

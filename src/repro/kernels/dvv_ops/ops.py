@@ -1,7 +1,9 @@
-"""Jitted public wrappers over the DVV Pallas kernel.
+"""Jitted public wrappers over the DVV Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the kernel body executes in Python
-on CPU for correctness); on TPU backends the compiled kernel runs.
+On a TPU backend the compiled kernels run.  On the CPU backend — the test
+path — they run in interpret mode (the kernel body executes as ordinary
+XLA ops, correct but slow).  Any other backend is an error: there is no
+quiet fallback from the device path.
 """
 from __future__ import annotations
 
@@ -10,13 +12,20 @@ import jax.numpy as jnp
 
 import numpy as np
 
-from ...core.batched import BucketedSyncMask, bucket_shape, merge_context, \
-    pad_sync_args
-from .dvv_ops import dvv_leq_pallas, dvv_sync_mask_pallas
+from ...core.batched import BucketedSyncMask, bucket_shape, pad_sync_args
+from .dvv_ops import dvv_leq_pallas, dvv_read_sweep_pallas, \
+    dvv_sync_mask_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"DVV kernels run compiled on TPU or interpreted on CPU; "
+        f"backend {backend!r} is neither")
 
 
 def dvv_leq(vx, ix, nx, vy, iy, ny):
@@ -50,18 +59,16 @@ def dvv_read_sweep(vvs, dot_ids, dot_ns, valid):
     The read plane's device-side primitive: the fused Pallas survival
     kernel produces the mask, and the ceiling ⌈S⌉ of each key's *surviving*
     rows falls out of the same resident tensor via ``merge_context`` (a
-    masked column max with the dots folded in) — no second gather of the
-    clock rows.  Returns ``(mask bool[N, K], ceil int32[N, R])``; semantics
-    equal ``core.batched.sync_mask_np`` + ``grouped_ceiling_np`` over the
+    masked column max with the dots folded in) in the same jitted program
+    (``dvv_read_sweep_pallas``) — no second gather of the clock rows.
+    Returns ``(mask bool[N, K], ceil int32[N, R])``; semantics equal
+    ``core.batched.sync_mask_np`` + ``grouped_ceiling_np`` over the
     surviving rows (conformance-tested in tests/test_read_path.py).
     Production reads enter through ``dvv_read_sweep_bucketed`` below.
     """
-    vvs = jnp.asarray(vvs)
-    dot_ids = jnp.asarray(dot_ids)
-    dot_ns = jnp.asarray(dot_ns)
-    mask = dvv_sync_mask_pallas(vvs, dot_ids, dot_ns, jnp.asarray(valid),
-                                interpret=_interpret())
-    return mask, merge_context(vvs, dot_ids, dot_ns, mask)
+    return dvv_read_sweep_pallas(jnp.asarray(vvs), jnp.asarray(dot_ids),
+                                 jnp.asarray(dot_ns), jnp.asarray(valid),
+                                 interpret=_interpret())
 
 
 class BucketedReadSweep:

@@ -17,6 +17,11 @@ millions of logical sessions, scheduler flush deadlines and (with
 
     PYTHONPATH=src python -m repro.launch.serve --store-workload \
         --store-mode both --sessions 1000000 --store-steps 1500
+
+``--use-kernel`` routes every clock sweep the workload makes (reads,
+writes, gossip) through the DVV Pallas kernels: compiled on a TPU,
+interpreted on the CPU backend.  The persistent compile cache goes where
+``JAX_COMPILATION_CACHE_DIR`` says, else to ``<repo>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -33,6 +38,7 @@ from ..configs import ARCH_IDS, get_config
 from ..core import DVV_MECHANISM
 from ..models import decode_step, init_cache, init_params
 from ..store import KVCluster, SimNetwork
+from .compile_cache import enable_compile_cache
 
 
 @dataclass
@@ -117,13 +123,14 @@ def store_workload_main(args: argparse.Namespace) -> int:
         driver = None
         if args.gossip_period > 0:
             driver = GossipDriver(cluster, period=args.gossip_period,
-                                  seed=7)
+                                  seed=7, use_kernel=args.use_kernel)
             driver.start()          # timers interleave with the engine
         eng = ClosedLoopEngine(
             cluster, sessions=args.sessions, keys=args.keys,
             zipf_s=args.zipf, concurrency=args.concurrency,
             mode=mode, via="n0", seed=args.seed, read_repair=True,
-            max_batch=args.max_batch, max_delay=args.max_delay)
+            max_batch=args.max_batch, max_delay=args.max_delay,
+            use_kernel=args.use_kernel)
         out = eng.run(args.store_steps)
         if driver is not None:
             out["gossip"] = {"rounds": driver.rounds,
@@ -164,7 +171,10 @@ def main() -> int:
     g.add_argument("--gossip-period", type=float, default=0.0,
                    help="anti-entropy period in sim ticks (0 = off)")
     g.add_argument("--seed", type=int, default=11)
+    g.add_argument("--use-kernel", action="store_true",
+                   help="run the clock sweeps on the DVV Pallas kernels")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.store_workload:
         return store_workload_main(args)

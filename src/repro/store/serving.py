@@ -575,7 +575,9 @@ class ClosedLoopEngine:
                  use_kernel: bool = False,
                  scheduler: Optional[OpScheduler] = None,
                  max_batch: int = 64, max_delay: float = 2.0,
-                 pump_period: float = 5.0):
+                 pump_period: float = 5.0, record_bytes: int = 0,
+                 observe: Optional[Callable[[int, str, Optional[GetResult]],
+                                            None]] = None):
         if mode not in ("coalesced", "direct"):
             raise ValueError(f"unknown mode {mode!r}")
         self.cluster = cluster
@@ -593,6 +595,12 @@ class ClosedLoopEngine:
         self.mode = mode
         self.via = via or next(iter(cluster.nodes))
         self.pump_period = pump_period
+        # PUT values are padded to ``record_bytes`` characters (a YCSB
+        # record is rewritten whole); 0 keeps the bare session tag.
+        self.record_bytes = record_bytes
+        # observe(session, key, GetResult | None) sees every GET's outcome
+        # before its PUT is issued — how a caller checks two planes agree.
+        self.observe = observe
         import random
         self.rng = random.Random(seed)
         # zipf CDF over key ranks; one searchsorted per draw
@@ -654,13 +662,15 @@ class ClosedLoopEngine:
         self._do_put(res, sid, key)
 
     def _do_put(self, res: Optional[GetResult], sid: int, key: str) -> None:
+        if self.observe is not None:
+            self.observe(sid, key, res)
         if res is None:                  # get failed: retry after thinking
             self._finish_step(sid)
             return
         # carry the token as wire bytes — the codec memo's hot loop
         token = self.client.encode_context(res.context)
         self._tokens[sid] = token
-        value = f"s{sid}.{self.steps_started}"
+        value = f"s{sid}.{self.steps_started}".ljust(self.record_bytes, ".")
         if self.rmw_time:
             delay = self.rmw_time * (0.5 + self.rng.random())
             self.network.schedule(

@@ -70,10 +70,13 @@ def test_dvv_concurrent_and_dominates_consistency():
 
 
 @pytest.mark.parametrize("n_replicas", [1, 3, 9])
-@pytest.mark.parametrize("n_keys,max_versions", [(1, 1), (19, 4), (150, 6)])
+@pytest.mark.parametrize("n_keys,max_versions", [
+    (1, 1), (19, 4), (150, 6), (700, 2), (530, 8), (150, 16)])
 def test_dvv_sync_mask_fused_kernel_sweep(n_replicas, n_keys, max_versions):
     """The fused pairwise-dominance kernel equals the jnp sync_mask
-    reference on randomized per-key clock sets (incl. invalid padding)."""
+    reference on randomized per-key clock sets (incl. invalid padding).
+    The last three cases cover K = 2, 8, 16 with N not a multiple of the
+    key block, across one and several grid steps."""
     rng = random.Random(n_replicas * 7919 + n_keys + max_versions)
     universe = [f"r{i}" for i in range(n_replicas)]
     vvs = np.zeros((n_keys, max_versions, n_replicas), np.int32)
