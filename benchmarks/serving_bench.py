@@ -23,7 +23,8 @@ buys and what it costs:
 Three sections: the session-count sweep (10k → 1M logical sessions), the
 flush-policy frontier (``max_delay`` x ``max_batch`` at 1M sessions), and
 the §6.4 kernel-path leg reporting cross-flush shape-bucket cache hit
-rates (``reset_stats`` before the measured window, ``cache_info`` after).
+rates (``hits``/``misses`` taken as differences across the measured
+window).
 
 Run ``make bench-serving`` → ``BENCH_serving.json``.
 """
@@ -141,10 +142,17 @@ def kernel_cache_rows(sessions: int, steps: int, trace: list,
               "sync_mask_jnp": sync_mask_bucketed}
     warm = _run_mode("coalesced", sessions, max(steps // 4, 50),
                      use_kernel=True, **wk)      # compile/warm the buckets
-    for cache in caches.values():
-        cache.reset_stats()
+    before = {name: (cache.hits, cache.misses)
+              for name, cache in caches.items()}
     c = _run_mode("coalesced", sessions, steps, use_kernel=True, **wk)
-    info = {name: cache.cache_info() for name, cache in caches.items()}
+    info = {}
+    for name, cache in caches.items():
+        hits = cache.hits - before[name][0]
+        misses = cache.misses - before[name][1]
+        info[name] = {"hits": hits, "misses": misses,
+                      "hit_rate": round(hits / (hits + misses), 4)
+                      if hits + misses else 0.0,
+                      "buckets": cache.cache_info()["buckets"]}
     row = {
         "section": "kernel_bucket_cache",
         "sessions": sessions, "ops": c["ops"],

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace
 from .dvv import DVV
 
 NO_DOT = -1
@@ -351,25 +352,25 @@ class BucketedSyncMask:
         key = bucket_shape(N, K, R)
         if key in self._seen:
             self.hits += 1
+            name = trace.KERNEL_FRONT
         else:
             self.misses += 1
             self._seen.add(key)
-        args = pad_sync_args(vvs, dot_ids, dot_ns, valid, key)
-        out = np.asarray(self._fn(*args))
-        return out[:N, :K]
+            name = trace.KERNEL_FRONT_COLD
+        with trace.span(name):
+            with trace.span(trace.KERNEL_PAD):
+                args = pad_sync_args(vvs, dot_ids, dot_ns, valid, key)
+            with trace.span(trace.KERNEL_DISPATCH):
+                out = self._fn(*args)
+            with trace.span(trace.KERNEL_FETCH):
+                out = np.asarray(out)
+            return out[:N, :K]
 
     def cache_info(self) -> Dict[str, object]:
         total = self.hits + self.misses
         return {"hits": self.hits, "misses": self.misses,
                 "hit_rate": round(self.hits / total, 4) if total else 0.0,
                 "buckets": sorted(self._seen)}
-
-    def reset_stats(self) -> None:
-        """Zero the hit/miss counters; the bucket set (and the compiled
-        callables behind it) stays warm.  Lets the serving benchmark
-        report cross-flush hit rates per measurement window."""
-        self.hits = 0
-        self.misses = 0
 
 
 #: Module-level jnp-reference instance.  Product delta rounds use the numpy

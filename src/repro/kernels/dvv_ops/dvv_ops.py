@@ -115,6 +115,14 @@ def dvv_sync_mask_pallas(vvs, dot_ids, dot_ns, valid, *,
     vvs: int32[N, K, R]; dot_ids/dot_ns: int32[N, K]; valid: bool[N, K].
     Returns bool[N, K].  Semantics identical to ``core.batched.sync_mask``.
     """
+    return _survival_mask(vvs, dot_ids, dot_ns, valid, interpret,
+                          "dvv_sync_mask")
+
+
+def _survival_mask(vvs, dot_ids, dot_ns, valid, interpret: bool,
+                   name: str):
+    """The survival kernel's launch, named ``name`` on the device trace
+    (the write path's and the read sweep's masks run the same kernel)."""
     N, K, R = vvs.shape
     if N == 0 or K == 0:
         return jnp.zeros((N, K), bool)
@@ -138,6 +146,7 @@ def dvv_sync_mask_pallas(vvs, dot_ids, dot_ns, valid, *,
         out_specs=narrow,
         out_shape=jax.ShapeDtypeStruct((K, Np), jnp.int32),
         interpret=interpret,
+        name=name,
     )(vv_t, rows(dot_ids, NO_DOT), rows(dot_ns), rows(valid))
     return out[:, :N].T != 0
 
@@ -147,8 +156,8 @@ def dvv_read_sweep_pallas(vvs, dot_ids, dot_ns, valid, *,
                           interpret: bool = True):
     """Survival mask and per-key §5.4 ceiling of the survivors, one
     program: ``(bool[N, K], int32[N, R])``."""
-    mask = dvv_sync_mask_pallas(vvs, dot_ids, dot_ns, valid,
-                                interpret=interpret)
+    mask = _survival_mask(vvs, dot_ids, dot_ns, valid, interpret,
+                          "dvv_read_sweep_mask")
     return mask, merge_context(vvs, dot_ids, dot_ns, mask)
 
 
@@ -179,6 +188,7 @@ def dvv_leq_pallas(vx, ix, nx, vy, iy, ny, *, interpret: bool = True):
         out_specs=narrow,
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
         interpret=interpret,
+        name="dvv_leq",
     )(clocks(vx), row(ix, NO_DOT), row(nx), clocks(vy), row(iy, NO_DOT),
       row(ny))
     return out[0, :N] != 0

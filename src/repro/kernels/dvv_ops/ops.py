@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 import numpy as np
 
+from ... import trace
 from ...core.batched import BucketedSyncMask, bucket_shape, pad_sync_args
 from .dvv_ops import dvv_leq_pallas, dvv_read_sweep_pallas, \
     dvv_sync_mask_pallas
@@ -98,25 +99,25 @@ class BucketedReadSweep:
         key = bucket_shape(N, K, R)
         if key in self._seen:
             self.hits += 1
+            name = trace.KERNEL_FRONT
         else:
             self.misses += 1
             self._seen.add(key)
-        args = pad_sync_args(vvs, dot_ids, dot_ns, valid, key)
-        mask, ceil = dvv_read_sweep(*args)
-        return (np.asarray(mask)[:N, :K],
-                np.asarray(ceil)[:N, :R].astype(np.int64))
+            name = trace.KERNEL_FRONT_COLD
+        with trace.span(name):
+            with trace.span(trace.KERNEL_PAD):
+                args = pad_sync_args(vvs, dot_ids, dot_ns, valid, key)
+            with trace.span(trace.KERNEL_DISPATCH):
+                mask, ceil = dvv_read_sweep(*args)
+            with trace.span(trace.KERNEL_FETCH):
+                mask, ceil = np.asarray(mask), np.asarray(ceil)
+            return mask[:N, :K], ceil[:N, :R].astype(np.int64)
 
     def cache_info(self) -> dict:
         total = self.hits + self.misses
         return {"hits": self.hits, "misses": self.misses,
                 "hit_rate": round(self.hits / total, 4) if total else 0.0,
                 "buckets": sorted(self._seen)}
-
-    def reset_stats(self) -> None:
-        """Zero the counters without cooling the bucket set — per-window
-        cross-flush hit-rate accounting (mirrors ``BucketedSyncMask``)."""
-        self.hits = 0
-        self.misses = 0
 
 
 #: Module-level instance (one shared bucket cache, like

@@ -22,10 +22,17 @@ millions of logical sessions, scheduler flush deadlines and (with
 writes, gossip) through the DVV Pallas kernels: compiled on a TPU,
 interpreted on the CPU backend.  The persistent compile cache goes where
 ``JAX_COMPILATION_CACHE_DIR`` says, else to ``<repo>/.jax_cache``.
+
+``--trace-dir DIR`` writes one profile of the workload under ``DIR``,
+which turns on the store's own spans and counters (``repro.trace``): the
+spans land on the host plane, on the device planes' clock (open it with
+TensorBoard or Perfetto), and each mode's span and counter table is added
+to its JSON summary as ``"trace"``.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 from dataclasses import dataclass, field
@@ -34,6 +41,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import trace
 from ..configs import ARCH_IDS, get_config
 from ..core import DVV_MECHANISM
 from ..models import decode_step, init_cache, init_params
@@ -115,29 +123,38 @@ def store_workload_main(args: argparse.Namespace) -> int:
     modes = (("coalesced", "direct") if args.store_mode == "both"
              else (args.store_mode,))
     summaries = {}
-    for mode in modes:
-        net = SimNetwork(seed=7, jitter=0.0)
-        cluster = KVCluster(tuple(f"n{i}" for i in range(5)),
-                            DVV_MECHANISM, replication=3, network=net,
-                            read_quorum=2, write_quorum=2, seed=7)
-        driver = None
-        if args.gossip_period > 0:
-            driver = GossipDriver(cluster, period=args.gossip_period,
-                                  seed=7, use_kernel=args.use_kernel)
-            driver.start()          # timers interleave with the engine
-        eng = ClosedLoopEngine(
-            cluster, sessions=args.sessions, keys=args.keys,
-            zipf_s=args.zipf, concurrency=args.concurrency,
-            mode=mode, via="n0", seed=args.seed, read_repair=True,
-            max_batch=args.max_batch, max_delay=args.max_delay,
-            use_kernel=args.use_kernel)
-        out = eng.run(args.store_steps)
-        if driver is not None:
-            out["gossip"] = {"rounds": driver.rounds,
-                             "wire_bytes": driver.wire_bytes()}
-            driver.stop()
-        summaries[mode] = out
-        print(json.dumps(out, indent=1))
+    profile = contextlib.nullcontext()
+    if args.trace_dir:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0     # the store's spans, not every call
+        profile = jax.profiler.trace(args.trace_dir, profiler_options=opts)
+    with profile:
+        for mode in modes:
+            net = SimNetwork(seed=7, jitter=0.0)
+            cluster = KVCluster(tuple(f"n{i}" for i in range(5)),
+                                DVV_MECHANISM, replication=3, network=net,
+                                read_quorum=2, write_quorum=2, seed=7)
+            driver = None
+            if args.gossip_period > 0:
+                driver = GossipDriver(cluster, period=args.gossip_period,
+                                      seed=7, use_kernel=args.use_kernel)
+                driver.start()          # timers interleave with the engine
+            eng = ClosedLoopEngine(
+                cluster, sessions=args.sessions, keys=args.keys,
+                zipf_s=args.zipf, concurrency=args.concurrency,
+                mode=mode, via="n0", seed=args.seed, read_repair=True,
+                max_batch=args.max_batch, max_delay=args.max_delay,
+                use_kernel=args.use_kernel)
+            before = trace.snapshot()
+            out = eng.run(args.store_steps)
+            if args.trace_dir:
+                out["trace"] = trace.delta(before, trace.snapshot())
+            if driver is not None:
+                out["gossip"] = {"rounds": driver.rounds,
+                                 "wire_bytes": driver.wire_bytes()}
+                driver.stop()
+            summaries[mode] = out
+            print(json.dumps(out, indent=1))
     if len(summaries) == 2:
         d, c = summaries["direct"], summaries["coalesced"]
         if c["plane_per_1k_ops"]:
@@ -173,6 +190,9 @@ def main() -> int:
     g.add_argument("--seed", type=int, default=11)
     g.add_argument("--use-kernel", action="store_true",
                    help="run the clock sweeps on the DVV Pallas kernels")
+    g.add_argument("--trace-dir", default=None,
+                   help="write a profile with the store's spans here and "
+                        "add the span table to the summary")
     args = ap.parse_args()
     enable_compile_cache()
 

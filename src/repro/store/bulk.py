@@ -30,6 +30,7 @@ from typing import Dict, FrozenSet, Iterable, List, Mapping, Optional, \
 
 import numpy as np
 
+from .. import trace
 from .packed import PackedPayload, PackedVersionStore, StoreDigest
 from .replica import PackedBackend, ReplicaNode, _as_object_payload
 from .sharding import shard_of_key
@@ -125,31 +126,32 @@ def _store_delta_round(src_store: PackedVersionStore,
                        mask_fn=None, max_ranges: Optional[int] = None,
                        shard: int = -1) -> DeltaSyncStats:
     """The two-phase round between two packed stores (one shard's plane)."""
-    dst_digest = dst_store.sync_digest()
-    ranked, width, n_divergent = delta_plan(src_store, dst_digest,
-                                            max_ranges=max_ranges)
-    # Phase-1 wire: each side's tree travels folded to the common width,
-    # plus one 8-byte value root per side (the content check below).
-    digest_bytes = 2 * (dst_digest.fold(width).nbytes() + 8)
-    if len(ranked) == 0:
-        if src_store.value_root() != dst_store.value_root():
-            # The §6.1 hashes cover clock+key only, so clock-equal/value-
-            # different slots (only reachable through non-protocol
-            # ``bulk_sync`` dicts) diff to zero divergent buckets.  The
-            # value roots disagree exactly then: run the full-payload
-            # round rather than silently reporting convergence.
-            payload = src_store.payload()
-            changed = dst_store.apply_payload(payload, mask_fn=mask_fn)
-            return DeltaSyncStats(width, 0, 0, len(payload),
-                                  payload.nbytes(), digest_bytes, changed,
-                                  fallback=True, shard=shard)
+    with trace.span(trace.AE_DIGEST):
+        dst_digest = dst_store.sync_digest()
+        ranked, width, n_divergent = delta_plan(src_store, dst_digest,
+                                                max_ranges=max_ranges)
+        # Phase-1 wire: each side's tree travels folded to the common
+        # width, plus one 8-byte value root per side (the content check
+        # below).
+        digest_bytes = 2 * (dst_digest.fold(width).nbytes() + 8)
+        # The §6.1 hashes cover clock+key only, so clock-equal/value-
+        # different slots (only reachable through non-protocol
+        # ``bulk_sync`` dicts) diff to zero divergent buckets.  The value
+        # roots disagree exactly then: run the full-payload round rather
+        # than silently reporting convergence.
+        fallback = len(ranked) == 0 and \
+            src_store.value_root() != dst_store.value_root()
+    if len(ranked) == 0 and not fallback:
         return DeltaSyncStats(width, 0, 0, 0, 0, digest_bytes, 0,
                               shard=shard)
-    payload = src_store.payload(key_ranges=ranked, ranges_width=width)
-    changed = dst_store.apply_payload(payload, mask_fn=mask_fn)
-    return DeltaSyncStats(width, n_divergent, len(ranked),
+    with trace.span(trace.AE_PAYLOAD):
+        payload = src_store.payload() if fallback else \
+            src_store.payload(key_ranges=ranked, ranges_width=width)
+    with trace.span(trace.AE_APPLY):
+        changed = dst_store.apply_payload(payload, mask_fn=mask_fn)
+    return DeltaSyncStats(width, 0 if fallback else n_divergent, len(ranked),
                           len(payload), payload.nbytes(), digest_bytes,
-                          changed, shard=shard)
+                          changed, fallback=fallback, shard=shard)
 
 
 def _shard_budget(max_ranges: RangeBudget, shard: int) -> Optional[int]:

@@ -23,6 +23,7 @@ from typing import Any, Dict, FrozenSet, Iterable, List, Mapping, Optional, \
 
 import numpy as np
 
+from .. import trace
 from ..core.kernel import Mechanism
 from .bulk import DeltaSyncStats, RangeBudget, \
     delta_antientropy as _delta_antientropy
@@ -698,29 +699,30 @@ class KVCluster:
         # ONE atomic pass across all shards; keys sharing a placement slice
         # share one reachability resolution (same replica set, same fabric
         # state within the call).
-        chosen: Dict[str, List[str]] = {}
-        short: List[str] = []
-        slice_reach: Dict[int, List[str]] = {}
-        for key in keys:
-            sl = shard_of_key(key, self._slices)
-            reachable = slice_reach.get(sl)
-            if reachable is None:
-                reachable = slice_reach[sl] = \
-                    self._reachable_replicas(proxy, key)
-            if len(reachable) < quorum:
-                short.append(key)
-            else:
-                chosen[key] = reachable[: max(quorum, 1)]
-        if short:
-            raise Unavailable(
-                f"read quorum {quorum} unreachable for {len(short)}/"
-                f"{len(chosen) + len(short)} keys via {proxy} "
-                f"(e.g. {short[:3]})")
+        with trace.span(trace.PLANE_GET_ADMIT):
+            chosen: Dict[str, List[str]] = {}
+            short: List[str] = []
+            slice_reach: Dict[int, List[str]] = {}
+            for key in keys:
+                sl = shard_of_key(key, self._slices)
+                reachable = slice_reach.get(sl)
+                if reachable is None:
+                    reachable = slice_reach[sl] = \
+                        self._reachable_replicas(proxy, key)
+                if len(reachable) < quorum:
+                    short.append(key)
+                else:
+                    chosen[key] = reachable[: max(quorum, 1)]
+            if short:
+                raise Unavailable(
+                    f"read quorum {quorum} unreachable for {len(short)}/"
+                    f"{len(chosen) + len(short)} keys via {proxy} "
+                    f"(e.g. {short[:3]})")
+            packed_keys = [k for k, ids in chosen.items()
+                           if all(self.nodes[r].is_packed for r in ids)]
         results: Dict[str, GetResult] = {}
         packed_repairs: Dict[str, List[Tuple[str, MergedRead]]] = {}
         object_repairs: Dict[str, Dict[str, FrozenSet[Version]]] = {}
-        packed_keys = [k for k, ids in chosen.items()
-                       if all(self.nodes[r].is_packed for r in ids)]
         # one plane entry for the whole packed batch; each mixed/object
         # key below falls back to its own per-key merge (counted there)
         if packed_keys:
@@ -736,14 +738,15 @@ class KVCluster:
                 {k: [self.nodes[r].store_for(k) for r in chosen[k]]
                  for k in packed_keys},
                 packed_keys, sweep_fn=sweep_fn, track_stale=repair)
-            for k, m in merged.items():
-                results[k] = _merged_result(m.values, m.walls, m.clock_keys,
-                                            m.entries,
-                                            hlc=self._read_watermark(m.walls))
-                if repair:
-                    for j in m.stale:
-                        packed_repairs.setdefault(
-                            chosen[k][j], []).append((k, m))
+            with trace.span(trace.PLANE_GET_RESULT):
+                for k, m in merged.items():
+                    results[k] = _merged_result(
+                        m.values, m.walls, m.clock_keys, m.entries,
+                        hlc=self._read_watermark(m.walls))
+                    if repair:
+                        for j in m.stale:
+                            packed_repairs.setdefault(
+                                chosen[k][j], []).append((k, m))
         for k, ids in chosen.items():
             if k in results:
                 continue
@@ -755,21 +758,23 @@ class KVCluster:
                 for r in ids:
                     if self.nodes[r].versions(k) != acc:
                         object_repairs.setdefault(r, {})[k] = acc
-        if repair:
-            # A stale proxy repairs itself locally (it IS this process —
-            # no self-addressed wire message, no phantom bytes_sent); every
-            # other stale member gets its one consolidated push.
-            for dst, items in packed_repairs.items():
-                payload = _repair_payload(items)
-                if dst == proxy:
-                    self.nodes[dst].receive_antientropy(payload)
-                else:
-                    self.network.send(proxy, dst, ("store", payload))
-            for dst, payload in object_repairs.items():
-                if dst == proxy:
-                    self.nodes[dst].receive_antientropy(payload)
-                else:
-                    self.network.send(proxy, dst, ("store", payload))
+        if packed_repairs or object_repairs:
+            with trace.span(trace.PLANE_GET_REPAIR):
+                # A stale proxy repairs itself locally (it IS this process
+                # — no self-addressed wire message, no phantom
+                # bytes_sent); every other stale member gets its one
+                # consolidated push.
+                for dst, items in packed_repairs.items():
+                    payload = _repair_payload(items)
+                    if dst == proxy:
+                        self.nodes[dst].receive_antientropy(payload)
+                    else:
+                        self.network.send(proxy, dst, ("store", payload))
+                for dst, payload in object_repairs.items():
+                    if dst == proxy:
+                        self.nodes[dst].receive_antientropy(payload)
+                    else:
+                        self.network.send(proxy, dst, ("store", payload))
         return {k: results[k] for k in chosen}
 
     # -- causal snapshot reads (geo tier, DESIGN.md §12) --------------------
@@ -912,17 +917,22 @@ class KVCluster:
         walls: Dict[str, float] = {}
         coord_of: Dict[str, str] = {}
         slice_coord: Dict[int, str] = {}
-        for key, (value, context) in items.items():
-            ctxs[key] = CausalContext.coerce(context)
-            # one admission resolution per placement slice (atomic across
-            # shards: any key without a reachable coordinator raises here,
-            # before any store is touched)
-            sl = shard_of_key(key, self._slices)
-            coord = slice_coord.get(sl)
-            if coord is None:
-                coord = slice_coord[sl] = self._pick_coordinator(proxy, key)
-            coord_of[key] = coord
-            groups.setdefault(coord, []).append(key)
+        with trace.span(trace.PLANE_PUT_ADMIT):
+            for key, (value, context) in items.items():
+                ctxs[key] = CausalContext.coerce(context)
+                # one admission resolution per placement slice (atomic
+                # across shards: any key without a reachable coordinator
+                # raises here, before any store is touched)
+                sl = shard_of_key(key, self._slices)
+                coord = slice_coord.get(sl)
+                if coord is None:
+                    coord = slice_coord[sl] = self._pick_coordinator(proxy,
+                                                                     key)
+                coord_of[key] = coord
+                groups.setdefault(coord, []).append(key)
+            for key in items:
+                self.clock_time += 1.0
+                walls[key] = self._mint_wall(coord_of[key], ctxs[key], None)
         minted: Dict[str, Version] = {}
         acked: Dict[str, List[str]] = {}
         mask_fn = None
@@ -930,49 +940,48 @@ class KVCluster:
             from ..kernels.dvv_ops import dvv_sync_mask_bucketed
             mask_fn = dvv_sync_mask_bucketed
         geo = self.geo
-        for key in items:
-            self.clock_time += 1.0
-            walls[key] = self._mint_wall(coord_of[key], ctxs[key], None)
         for coord, keys in groups.items():
             self.plane_writes += 1
             cdc = geo.dc_of[coord] if geo is not None else None
             node = self.nodes[coord]
-            batch = [(k, ctxs[k], items[k][0], walls[k]) for k in keys]
-            versions = node.coordinate_updates(
-                batch, client_id=client_id, client_counter=client_counter,
-                mask_fn=mask_fn)
+            with trace.span(trace.PLANE_PUT_UPDATE):
+                batch = [(k, ctxs[k], items[k][0], walls[k]) for k in keys]
+                versions = node.coordinate_updates(
+                    batch, client_id=client_id,
+                    client_counter=client_counter, mask_fn=mask_fn)
             for k, v in zip(keys, versions):
                 minted[k] = v
                 acked[k] = [coord]
-            # One replication payload per destination: all of this
-            # coordinator's keys that destination replicates.  Geo mode
-            # fans out local-DC only (mirrors ride the WAN shipper).
-            dst_keys: Dict[str, List[str]] = {}
-            for k in keys:
-                for r in self.replicas_for(k):
-                    if r == coord:
-                        continue
-                    if geo is not None and geo.dc_of[r] != cdc:
-                        continue
-                    dst_keys.setdefault(r, []).append(k)
-            # Destinations replicating the same key set share one payload
-            # object (receivers never mutate payloads; single-key put
-            # already relies on this).
-            payload_cache: Dict[Tuple[str, ...], Any] = {}
-            for dst, ks in dst_keys.items():
-                sig = tuple(ks)
-                payload = payload_cache.get(sig)
-                if payload is None:
-                    payload = payload_cache[sig] = \
-                        node.antientropy_payload(ks)
-                if self.network.send(coord, dst, ("store", payload)):
-                    for k in ks:
-                        acked[k].append(dst)
-                elif geo is not None:
-                    for k in ks:
-                        geo.note_send_failed(coord, dst, walls[k])
-            if geo is not None:
-                geo.on_commit(cdc, tuple(walls[k] for k in keys))
+            with trace.span(trace.PLANE_PUT_REPLICATE):
+                # One replication payload per destination: all of this
+                # coordinator's keys that destination replicates.  Geo mode
+                # fans out local-DC only (mirrors ride the WAN shipper).
+                dst_keys: Dict[str, List[str]] = {}
+                for k in keys:
+                    for r in self.replicas_for(k):
+                        if r == coord:
+                            continue
+                        if geo is not None and geo.dc_of[r] != cdc:
+                            continue
+                        dst_keys.setdefault(r, []).append(k)
+                # Destinations replicating the same key set share one
+                # payload object (receivers never mutate payloads;
+                # single-key put already relies on this).
+                payload_cache: Dict[Tuple[str, ...], Any] = {}
+                for dst, ks in dst_keys.items():
+                    sig = tuple(ks)
+                    payload = payload_cache.get(sig)
+                    if payload is None:
+                        payload = payload_cache[sig] = \
+                            node.antientropy_payload(ks)
+                    if self.network.send(coord, dst, ("store", payload)):
+                        for k in ks:
+                            acked[k].append(dst)
+                    elif geo is not None:
+                        for k in ks:
+                            geo.note_send_failed(coord, dst, walls[k])
+                if geo is not None:
+                    geo.on_commit(cdc, tuple(walls[k] for k in keys))
         failed = [k for k in items if len(acked[k]) < quorum]
         if failed:
             raise Unavailable(

@@ -41,6 +41,7 @@ import warnings
 from dataclasses import dataclass
 from typing import Any, FrozenSet, Iterable, Iterator, Tuple
 
+from .. import trace
 from ..core.dvv import DVV
 
 _MAGIC = b"DCX1"                    # wire-format tag + version
@@ -179,18 +180,19 @@ class CausalContext:
         an 8-byte HLC watermark follows the entries.  A zero watermark is
         simply not encoded, so pre-geo tokens are byte-identical.  Residues
         (non-DVV mechanisms only) append a pickle blob last."""
-        flags = (1 if self.residue else 0) | (2 if self.hlc else 0)
-        parts = [_MAGIC, struct.pack("<BH", flags, len(self.entries))]
-        for r, n in self.entries:
-            rid = r.encode()
-            parts.append(struct.pack("<H", len(rid)))
-            parts.append(rid)
-            parts.append(struct.pack("<Q", n))
-        if self.hlc:
-            parts.append(struct.pack("<d", self.hlc))
-        if self.residue:
-            parts.append(pickle.dumps(self.residue))
-        return b"".join(parts)
+        with trace.span(trace.CODEC_ENCODE):
+            flags = (1 if self.residue else 0) | (2 if self.hlc else 0)
+            parts = [_MAGIC, struct.pack("<BH", flags, len(self.entries))]
+            for r, n in self.entries:
+                rid = r.encode()
+                parts.append(struct.pack("<H", len(rid)))
+                parts.append(rid)
+                parts.append(struct.pack("<Q", n))
+            if self.hlc:
+                parts.append(struct.pack("<d", self.hlc))
+            if self.residue:
+                parts.append(pickle.dumps(self.residue))
+            return b"".join(parts)
 
     @staticmethod
     def from_bytes(data: bytes) -> "CausalContext":
@@ -199,62 +201,64 @@ class CausalContext:
         rejected with ``ValueError`` before any entry escapes: a client
         handing us a corrupt token gets a clean error, never a context
         holding half its causal history."""
-        if len(data) < 4 or data[:4] != _MAGIC:
-            raise ValueError("not a CausalContext token (bad magic)")
-        if len(data) < 7:
-            raise ValueError("truncated CausalContext token (header)")
-        flags, count = struct.unpack_from("<BH", data, 4)
-        if flags & ~3:
-            raise ValueError("corrupt CausalContext token (flags)")
-        has_residue, has_hlc = flags & 1, flags & 2
-        off = 7
-        entries = []
-        for i in range(count):
-            if off + 2 > len(data):
-                raise ValueError(
-                    f"truncated CausalContext token (entry {i} length)")
-            (rlen,) = struct.unpack_from("<H", data, off)
-            off += 2
-            if off + rlen + 8 > len(data):
-                raise ValueError(
-                    f"truncated CausalContext token (entry {i} body)")
-            try:
-                rid = data[off: off + rlen].decode()
-            except UnicodeDecodeError as e:
-                raise ValueError(
-                    f"corrupt CausalContext token (entry {i} id)") from e
-            off += rlen
-            (n,) = struct.unpack_from("<Q", data, off)
-            off += 8
-            entries.append((rid, n))
-        hlc = 0.0
-        if has_hlc:
-            if off + 8 > len(data):
-                raise ValueError(
-                    "truncated CausalContext token (hlc watermark)")
-            (hlc,) = struct.unpack_from("<d", data, off)
-            off += 8
-            if not (hlc > 0.0):     # also rejects NaN, -0.0 and negatives
-                raise ValueError(
-                    "corrupt CausalContext token (hlc watermark)")
-        residue: Tuple[Any, ...] = ()
-        if has_residue:
-            stream = io.BytesIO(data[off:])
-            try:
-                residue = _ResidueUnpickler(stream).load()
-            except Exception as e:
-                raise ValueError(
-                    "corrupt CausalContext token (residue)") from e
-            if stream.read(1):       # pickle STOPs early on trailing bytes
+        with trace.span(trace.CODEC_DECODE):
+            if len(data) < 4 or data[:4] != _MAGIC:
+                raise ValueError("not a CausalContext token (bad magic)")
+            if len(data) < 7:
+                raise ValueError("truncated CausalContext token (header)")
+            flags, count = struct.unpack_from("<BH", data, 4)
+            if flags & ~3:
+                raise ValueError("corrupt CausalContext token (flags)")
+            has_residue, has_hlc = flags & 1, flags & 2
+            off = 7
+            entries = []
+            for i in range(count):
+                if off + 2 > len(data):
+                    raise ValueError(
+                        f"truncated CausalContext token (entry {i} length)")
+                (rlen,) = struct.unpack_from("<H", data, off)
+                off += 2
+                if off + rlen + 8 > len(data):
+                    raise ValueError(
+                        f"truncated CausalContext token (entry {i} body)")
+                try:
+                    rid = data[off: off + rlen].decode()
+                except UnicodeDecodeError as e:
+                    raise ValueError(
+                        f"corrupt CausalContext token (entry {i} id)") from e
+                off += rlen
+                (n,) = struct.unpack_from("<Q", data, off)
+                off += 8
+                entries.append((rid, n))
+            hlc = 0.0
+            if has_hlc:
+                if off + 8 > len(data):
+                    raise ValueError(
+                        "truncated CausalContext token (hlc watermark)")
+                (hlc,) = struct.unpack_from("<d", data, off)
+                off += 8
+                if not (hlc > 0.0):     # also rejects NaN, -0.0 and negatives
+                    raise ValueError(
+                        "corrupt CausalContext token (hlc watermark)")
+            residue: Tuple[Any, ...] = ()
+            if has_residue:
+                stream = io.BytesIO(data[off:])
+                try:
+                    residue = _ResidueUnpickler(stream).load()
+                except Exception as e:
+                    raise ValueError(
+                        "corrupt CausalContext token (residue)") from e
+                if stream.read(1):       # pickle STOPs early on trailing bytes
+                    raise ValueError(
+                        "corrupt CausalContext token (trailing bytes)")
+                if not isinstance(residue, tuple):
+                    raise ValueError(
+                        "corrupt CausalContext token (residue shape)")
+            elif off != len(data):
                 raise ValueError(
                     "corrupt CausalContext token (trailing bytes)")
-            if not isinstance(residue, tuple):
-                raise ValueError(
-                    "corrupt CausalContext token (residue shape)")
-        elif off != len(data):
-            raise ValueError("corrupt CausalContext token (trailing bytes)")
-        return CausalContext(entries=tuple(entries), residue=residue,
-                             hlc=hlc)
+            return CausalContext(entries=tuple(entries), residue=residue,
+                                 hlc=hlc)
 
     def __repr__(self) -> str:
         ent = ",".join(f"{r}:{n}" for r, n in self.entries)

@@ -114,7 +114,6 @@ class GossipDriver:
         self.payload_bytes = 0
         self.payload_slots = 0
         self.fallbacks = 0
-        self.divergent_ticks = 0
         self.suspect_probes = 0
         if autostart:
             self.start()
@@ -346,7 +345,6 @@ class GossipDriver:
                     and r.buckets_divergent > r.buckets_sent:
                 saturated = True
         if divergent:
-            self.divergent_ticks += 1
             st.idle_ticks = 0
             st.interval = self.period
             if saturated:

@@ -36,6 +36,8 @@ import random
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, FrozenSet, List, Optional, Set, Tuple
 
+from .. import trace
+
 
 class Unavailable(Exception):
     """Raised when a quorum cannot be assembled (CAP: we choose AP, but a
@@ -388,16 +390,19 @@ class SimNetwork:
         """
         count = 0
         while True:
-            ready = [m for m in self.queue
-                     if (until is None or m.deliver_at <= until)
-                     and self.reachable(m.src, m.dst)]
-            if not ready or (max_messages is not None and count >= max_messages):
-                break
-            ready.sort(key=lambda m: (m.deliver_at, m.src, m.dst))
-            msg = ready[0]
-            self.queue.remove(msg)
+            with trace.span(trace.NET_DELIVER_SCAN):
+                ready = [m for m in self.queue
+                         if (until is None or m.deliver_at <= until)
+                         and self.reachable(m.src, m.dst)]
+                if not ready or (max_messages is not None
+                                 and count >= max_messages):
+                    break
+                ready.sort(key=lambda m: (m.deliver_at, m.src, m.dst))
+                msg = ready[0]
+                self.queue.remove(msg)
             self.now = max(self.now, msg.deliver_at)
-            handler(msg)
+            with trace.span(trace.NET_APPLY):
+                handler(msg)
             count += 1
             self.delivered += 1
         return count

@@ -46,6 +46,7 @@ from typing import Any, Callable, Dict, FrozenSet, Iterable, List, Mapping, \
 
 import numpy as np
 
+from .. import trace
 from ..core import batched as B
 from ..core.dvv import DVV
 from .context import CausalContext
@@ -910,37 +911,39 @@ class PackedVersionStore:
         Returns ``(vv[M, R], r_ix, dot_n[M])`` for the minted clocks,
         aligned with ``updates``.
         """
-        keys = [u[0] for u in updates]
-        if len(set(keys)) != len(keys):
-            raise ValueError("update_keys requires distinct keys per batch")
-        r_ix = self.intern_replica(coordinator)
-        for _, entries, _, _ in updates:
-            for rid, _ in entries:
-                self.intern_replica(rid)
-        R = self.n_replicas
-        M = len(updates)
-        vv = np.zeros((M, R), np.int32)
-        for i, (_, entries, _, _) in enumerate(updates):
-            row = self.ceiling_of_entries(entries)   # universe pre-grown
-            vv[i, : len(row)] = row
-        # ⌈Sr⌉_r per key over the resident slots, one grouped scatter.
-        kixs = [self.intern_key(k) for k in keys]
-        lists = [self._slots_by_key.get(kx, []) for kx in kixs]
-        loc_rows = np.asarray([s for l in lists for s in l], np.int64)
-        loc_group = np.repeat(np.arange(M), [len(l) for l in lists])
-        local_max = B.grouped_ceil_at_np(
-            self.vv[loc_rows, r_ix], self.dot_id[loc_rows],
-            self.dot_n[loc_rows], loc_group, M, r_ix)
-        dot_n = (local_max + 1).astype(np.int32)
-        minted = PackedPayload(
-            replica_ids=tuple(self.replica_ids),
-            keys=tuple(keys),
-            vv=vv,
-            dot_id=np.full(M, r_ix, np.int32),
-            dot_n=dot_n,
-            key_ix=np.arange(M, dtype=np.int32),
-            values=tuple(u[2] for u in updates),
-            wall=np.asarray([u[3] for u in updates], np.float64))
+        with trace.span(trace.PACKED_GATHER):
+            keys = [u[0] for u in updates]
+            if len(set(keys)) != len(keys):
+                raise ValueError(
+                    "update_keys requires distinct keys per batch")
+            r_ix = self.intern_replica(coordinator)
+            for _, entries, _, _ in updates:
+                for rid, _ in entries:
+                    self.intern_replica(rid)
+            R = self.n_replicas
+            M = len(updates)
+            vv = np.zeros((M, R), np.int32)
+            for i, (_, entries, _, _) in enumerate(updates):
+                row = self.ceiling_of_entries(entries)   # universe pre-grown
+                vv[i, : len(row)] = row
+            # ⌈Sr⌉_r per key over the resident slots, one grouped scatter.
+            kixs = [self.intern_key(k) for k in keys]
+            lists = [self._slots_by_key.get(kx, []) for kx in kixs]
+            loc_rows = np.asarray([s for l in lists for s in l], np.int64)
+            loc_group = np.repeat(np.arange(M), [len(l) for l in lists])
+            local_max = B.grouped_ceil_at_np(
+                self.vv[loc_rows, r_ix], self.dot_id[loc_rows],
+                self.dot_n[loc_rows], loc_group, M, r_ix)
+            dot_n = (local_max + 1).astype(np.int32)
+            minted = PackedPayload(
+                replica_ids=tuple(self.replica_ids),
+                keys=tuple(keys),
+                vv=vv,
+                dot_id=np.full(M, r_ix, np.int32),
+                dot_n=dot_n,
+                key_ix=np.arange(M, dtype=np.int32),
+                values=tuple(u[2] for u in updates),
+                wall=np.asarray([u[3] for u in updates], np.float64))
         self.apply_payload(minted, mask_fn=mask_fn)
         return vv, r_ix, dot_n
 
@@ -1054,132 +1057,139 @@ class PackedVersionStore:
         M = len(payload)
         if M == 0:
             return 0
-        inc_vv, inc_did = self._remap_columns(payload)
-        inc_dn = payload.dot_n
-        # Collapse duplicate payload keys to one group each (a caller can
-        # legitimately request the same key twice, e.g. antientropy with a
-        # repeated key list); two groups for one key would double-insert.
-        key_ixs_all = np.asarray(
-            [self.intern_key(k) for k in payload.keys], np.int64)
-        key_ixs, inverse = np.unique(key_ixs_all, return_inverse=True)
-        R = self.n_replicas
-        N = len(key_ixs)
-        before_sets = None
-        if self.shadow_hook is not None:
-            before_sets = [self.versions(self.keys[int(kx)])
+        with trace.span(trace.PACKED_GATHER):
+            inc_vv, inc_did = self._remap_columns(payload)
+            inc_dn = payload.dot_n
+            # Collapse duplicate payload keys to one group each (a caller
+            # can legitimately request the same key twice, e.g. antientropy
+            # with a repeated key list); two groups for one key would
+            # double-insert.
+            key_ixs_all = np.asarray(
+                [self.intern_key(k) for k in payload.keys], np.int64)
+            key_ixs, inverse = np.unique(key_ixs_all, return_inverse=True)
+            R = self.n_replicas
+            N = len(key_ixs)
+            before_sets = None
+            if self.shadow_hook is not None:
+                before_sets = [self.versions(self.keys[int(kx)])
+                               for kx in key_ixs]
+
+            # One group per payload key; local resident slots occupy the
+            # first positions (duplicates keep the resident copy), incoming
+            # rows follow in payload order.
+            local_lists = [self._slots_by_key.get(int(kx), [])
                            for kx in key_ixs]
+            loc_counts = np.asarray([len(l) for l in local_lists], np.int64)
+            loc_rows = np.asarray(
+                [s for l in local_lists for s in l], dtype=np.int64)
+            loc_group = np.repeat(np.arange(N), loc_counts)
+            loc_start = np.zeros(N + 1, np.int64)
+            np.cumsum(loc_counts, out=loc_start[1:])
+            loc_pos = np.arange(len(loc_rows)) - loc_start[loc_group]
 
-        # One group per payload key; local resident slots occupy the first
-        # positions (duplicates keep the resident copy), incoming rows
-        # follow in payload order.
-        local_lists = [self._slots_by_key.get(int(kx), []) for kx in key_ixs]
-        loc_counts = np.asarray([len(l) for l in local_lists], np.int64)
-        loc_rows = np.asarray(
-            [s for l in local_lists for s in l], dtype=np.int64)
-        loc_group = np.repeat(np.arange(N), loc_counts)
-        loc_start = np.zeros(N + 1, np.int64)
-        np.cumsum(loc_counts, out=loc_start[1:])
-        loc_pos = np.arange(len(loc_rows)) - loc_start[loc_group]
+            inc_group = inverse[payload.key_ix]
+            order = np.argsort(inc_group, kind="stable")
+            sorted_g = inc_group[order]
+            run_start = np.searchsorted(sorted_g, np.arange(N))
+            inc_pos = np.empty(M, np.int64)
+            inc_pos[order] = np.arange(M) - run_start[sorted_g]
+            inc_pos += loc_counts[inc_group]
 
-        inc_group = inverse[payload.key_ix]
-        order = np.argsort(inc_group, kind="stable")
-        sorted_g = inc_group[order]
-        run_start = np.searchsorted(sorted_g, np.arange(N))
-        inc_pos = np.empty(M, np.int64)
-        inc_pos[order] = np.arange(M) - run_start[sorted_g]
-        inc_pos += loc_counts[inc_group]
-
-        counts = loc_counts + np.bincount(inc_group, minlength=N)
-        K = int(counts.max(initial=1))
-        vvs = np.zeros((N, K, R), np.int32)
-        dids = np.full((N, K), NO_DOT, np.int32)
-        dns = np.zeros((N, K), np.int32)
-        valid = np.zeros((N, K), bool)
-        if len(loc_rows):
-            vvs[loc_group, loc_pos] = self.vv[loc_rows, :R]
-            dids[loc_group, loc_pos] = self.dot_id[loc_rows]
-            dns[loc_group, loc_pos] = self.dot_n[loc_rows]
-            valid[loc_group, loc_pos] = True
-        vvs[inc_group, inc_pos] = inc_vv
-        dids[inc_group, inc_pos] = inc_did
-        dns[inc_group, inc_pos] = inc_dn
-        valid[inc_group, inc_pos] = True
+            counts = loc_counts + np.bincount(inc_group, minlength=N)
+            K = int(counts.max(initial=1))
+            vvs = np.zeros((N, K, R), np.int32)
+            dids = np.full((N, K), NO_DOT, np.int32)
+            dns = np.zeros((N, K), np.int32)
+            valid = np.zeros((N, K), bool)
+            if len(loc_rows):
+                vvs[loc_group, loc_pos] = self.vv[loc_rows, :R]
+                dids[loc_group, loc_pos] = self.dot_id[loc_rows]
+                dns[loc_group, loc_pos] = self.dot_n[loc_rows]
+                valid[loc_group, loc_pos] = True
+            vvs[inc_group, inc_pos] = inc_vv
+            dids[inc_group, inc_pos] = inc_did
+            dns[inc_group, inc_pos] = inc_dn
+            valid[inc_group, inc_pos] = True
 
         if mask_fn is None:
-            mask = B.sync_mask_np(vvs, dids, dns, valid)
+            with trace.span(trace.PACKED_MASK):
+                mask = B.sync_mask_np(vvs, dids, dns, valid)
         else:
             mask = np.asarray(mask_fn(vvs, dids, dns, valid))
 
-        # -- write-back: masked kill of local slots ------------------------
-        changed_groups = np.zeros(N, bool)
-        if len(loc_rows):
-            loc_keep = mask[loc_group, loc_pos]
-            dead_rows = loc_rows[~loc_keep]
-            if len(dead_rows):
-                self._digest_kill(dead_rows)
-                self._index_kill(dead_rows)
-                self.valid[dead_rows] = False
-                self.n_dead += len(dead_rows)
-                dead_set = set(dead_rows.tolist())
-                for g in np.unique(loc_group[~loc_keep]):
-                    kix = int(key_ixs[g])
-                    self._slots_by_key[kix] = [
-                        s for s in self._slots_by_key[kix]
-                        if s not in dead_set]
-                changed_groups[loc_group[~loc_keep]] = True
+        with trace.span(trace.PACKED_SCATTER):
+            # -- write-back: masked kill of local slots --------------------
+            changed_groups = np.zeros(N, bool)
+            if len(loc_rows):
+                loc_keep = mask[loc_group, loc_pos]
+                dead_rows = loc_rows[~loc_keep]
+                if len(dead_rows):
+                    self._digest_kill(dead_rows)
+                    self._index_kill(dead_rows)
+                    self.valid[dead_rows] = False
+                    self.n_dead += len(dead_rows)
+                    dead_set = set(dead_rows.tolist())
+                    for g in np.unique(loc_group[~loc_keep]):
+                        kix = int(key_ixs[g])
+                        self._slots_by_key[kix] = [
+                            s for s in self._slots_by_key[kix]
+                            if s not in dead_set]
+                    changed_groups[loc_group[~loc_keep]] = True
 
-        # -- write-back: bulk append of surviving incoming rows ------------
-        new_rows = np.flatnonzero(mask[inc_group, inc_pos])
-        n_new = len(new_rows)
-        if n_new:
-            self._ensure_capacity(n_new)
-            s0 = self.n_slots
-            dst = s0 + np.arange(n_new)
-            self.vv[dst, :R] = inc_vv[new_rows]
-            self.vv[dst, R:] = 0
-            self.dot_id[dst] = inc_did[new_rows]
-            self.dot_n[dst] = inc_dn[new_rows]
-            self.wall[dst] = payload.wall[new_rows]
-            new_max = float(payload.wall[new_rows].max())
-            if new_max > self.max_wall:
-                self.max_wall = new_max
-            groups_new = inc_group[new_rows]
-            kix_new = key_ixs[groups_new]
-            self.key_ix[dst] = kix_new
-            self.valid[dst] = True
-            new_buckets = self._key_bucket[kix_new]
-            if self.track_digests:
-                new_hashes = self._slot_hash_rows(
-                    inc_vv[new_rows], inc_did[new_rows], inc_dn[new_rows],
-                    kix_new)
-                self.slot_hash[dst] = new_hashes
-                np.bitwise_xor.at(self.digest, new_buckets, new_hashes)
-                np.add.at(self._bucket_live, new_buckets, 1)
-                self._digest_root ^= int(np.bitwise_xor.reduce(new_hashes))
-                vhs = np.asarray([_hash_value(payload.values[int(r)])
-                                  for r in new_rows], _U64)
-                self.val_hash[dst] = vhs
-                self._value_root ^= int(np.bitwise_xor.reduce(
-                    _mix64(new_hashes ^ vhs)))
-            for i, row in enumerate(new_rows):
-                self.values[s0 + i] = payload.values[int(row)]
-                self._slots_by_key[int(kix_new[i])].append(s0 + i)
-                self._bucket_slots.setdefault(
-                    int(new_buckets[i]), set()).add(s0 + i)
-            self.n_slots += n_new
-            changed_groups[groups_new] = True
+            # -- write-back: bulk append of surviving incoming rows --------
+            new_rows = np.flatnonzero(mask[inc_group, inc_pos])
+            n_new = len(new_rows)
+            if n_new:
+                self._ensure_capacity(n_new)
+                s0 = self.n_slots
+                dst = s0 + np.arange(n_new)
+                self.vv[dst, :R] = inc_vv[new_rows]
+                self.vv[dst, R:] = 0
+                self.dot_id[dst] = inc_did[new_rows]
+                self.dot_n[dst] = inc_dn[new_rows]
+                self.wall[dst] = payload.wall[new_rows]
+                new_max = float(payload.wall[new_rows].max())
+                if new_max > self.max_wall:
+                    self.max_wall = new_max
+                groups_new = inc_group[new_rows]
+                kix_new = key_ixs[groups_new]
+                self.key_ix[dst] = kix_new
+                self.valid[dst] = True
+                new_buckets = self._key_bucket[kix_new]
+                if self.track_digests:
+                    new_hashes = self._slot_hash_rows(
+                        inc_vv[new_rows], inc_did[new_rows],
+                        inc_dn[new_rows], kix_new)
+                    self.slot_hash[dst] = new_hashes
+                    np.bitwise_xor.at(self.digest, new_buckets, new_hashes)
+                    np.add.at(self._bucket_live, new_buckets, 1)
+                    self._digest_root ^= int(
+                        np.bitwise_xor.reduce(new_hashes))
+                    vhs = np.asarray([_hash_value(payload.values[int(r)])
+                                      for r in new_rows], _U64)
+                    self.val_hash[dst] = vhs
+                    self._value_root ^= int(np.bitwise_xor.reduce(
+                        _mix64(new_hashes ^ vhs)))
+                for i, row in enumerate(new_rows):
+                    self.values[s0 + i] = payload.values[int(row)]
+                    self._slots_by_key[int(kix_new[i])].append(s0 + i)
+                    self._bucket_slots.setdefault(
+                        int(new_buckets[i]), set()).add(s0 + i)
+                self.n_slots += n_new
+                changed_groups[groups_new] = True
 
-        if before_sets is not None:
-            for g in np.flatnonzero(changed_groups):
-                bs = before_sets[int(g)]
-                if bs:
-                    self.shadow_hook(self.keys[int(key_ixs[int(g)])], bs)
-        self.compact()
-        self._maybe_grow_buckets()
-        if self.wal_hook is not None and changed_groups.any():
-            changed_keys = [self.keys[int(key_ixs[int(g)])]
-                            for g in np.flatnonzero(changed_groups)]
-            self.wal_hook(self.payload(keys=changed_keys))
+            if before_sets is not None:
+                for g in np.flatnonzero(changed_groups):
+                    bs = before_sets[int(g)]
+                    if bs:
+                        self.shadow_hook(
+                            self.keys[int(key_ixs[int(g)])], bs)
+            self.compact()
+            self._maybe_grow_buckets()
+            if self.wal_hook is not None and changed_groups.any():
+                changed_keys = [self.keys[int(key_ixs[int(g)])]
+                                for g in np.flatnonzero(changed_groups)]
+                self.wal_hook(self.payload(keys=changed_keys))
         return int(changed_groups.sum())
 
     # -- misc ---------------------------------------------------------------
@@ -1313,129 +1323,139 @@ def quorum_merge_many(stores_by_key: Mapping[str,
         groups.setdefault(
             tuple(id(st) for st in stores_by_key[k]), []).append(k)
     for gkeys in groups.values():
-        stores = list(stores_by_key[gkeys[0]])
-        N = len(gkeys)
-        # Union replica universe + per-store column maps, built ONCE per
-        # group — the per-key rebuild was the looped read path's tax.
-        ids: List[str] = []
-        index: Dict[str, int] = {}
-        col_maps: List[np.ndarray] = []
-        for st in stores:
-            cols = np.empty(st.n_replicas, np.int64)
-            for j, rid in enumerate(st.replica_ids):
-                ix = index.get(rid)
-                if ix is None:
-                    ix = index[rid] = len(ids)
-                    ids.append(rid)
-                cols[j] = ix
-            col_maps.append(cols)
-        Ru = len(ids)
-        # One gather per store: all of its rows for all group keys at once.
-        chunk_vv, chunk_did, chunk_dn, chunk_wall = [], [], [], []
-        chunk_group, chunk_src = [], []
-        values: List[Any] = []
-        for j, (st, cols) in enumerate(zip(stores, col_maps)):
-            lists = [st.key_slots(k) for k in gkeys]
-            rows = np.asarray([s for l in lists for s in l], np.int64)
-            if not len(rows):
+        with trace.span(trace.PACKED_GATHER):
+            stores = list(stores_by_key[gkeys[0]])
+            N = len(gkeys)
+            # Union replica universe + per-store column maps, built ONCE
+            # per group — the per-key rebuild was the looped read path's
+            # tax.
+            ids: List[str] = []
+            index: Dict[str, int] = {}
+            col_maps: List[np.ndarray] = []
+            for st in stores:
+                cols = np.empty(st.n_replicas, np.int64)
+                for j, rid in enumerate(st.replica_ids):
+                    ix = index.get(rid)
+                    if ix is None:
+                        ix = index[rid] = len(ids)
+                        ids.append(rid)
+                    cols[j] = ix
+                col_maps.append(cols)
+            Ru = len(ids)
+            # One gather per store: all of its rows for all group keys at
+            # once.
+            chunk_vv, chunk_did, chunk_dn, chunk_wall = [], [], [], []
+            chunk_group, chunk_src = [], []
+            values: List[Any] = []
+            for j, (st, cols) in enumerate(zip(stores, col_maps)):
+                lists = [st.key_slots(k) for k in gkeys]
+                rows = np.asarray([s for l in lists for s in l], np.int64)
+                if not len(rows):
+                    continue
+                cv, cdid = remap_rows(st.vv[rows, : st.n_replicas],
+                                      st.dot_id[rows], cols, Ru)
+                chunk_vv.append(cv)
+                chunk_did.append(cdid)
+                chunk_dn.append(st.dot_n[rows])
+                chunk_wall.append(st.wall[rows])
+                chunk_group.append(
+                    np.repeat(np.arange(N), [len(l) for l in lists]))
+                chunk_src.append(np.full(len(rows), j, np.int64))
+                values.extend(st.values[int(s)] for s in rows)
+            if not chunk_vv:                  # no store holds any group key
+                for key in gkeys:
+                    out[key] = MergedRead(
+                        tuple(ids), np.zeros((0, Ru), np.int32),
+                        np.zeros(0, np.int32), np.zeros(0, np.int32),
+                        [], [], [], ())
                 continue
-            cv, cdid = remap_rows(st.vv[rows, : st.n_replicas],
-                                  st.dot_id[rows], cols, Ru)
-            chunk_vv.append(cv)
-            chunk_did.append(cdid)
-            chunk_dn.append(st.dot_n[rows])
-            chunk_wall.append(st.wall[rows])
-            chunk_group.append(
-                np.repeat(np.arange(N), [len(l) for l in lists]))
-            chunk_src.append(np.full(len(rows), j, np.int64))
-            values.extend(st.values[int(s)] for s in rows)
-        if not chunk_vv:                      # no store holds any group key
-            for key in gkeys:
-                out[key] = MergedRead(tuple(ids), np.zeros((0, Ru), np.int32),
-                                      np.zeros(0, np.int32),
-                                      np.zeros(0, np.int32), [], [], [], ())
-            continue
-        vv = np.concatenate(chunk_vv)
-        did = np.concatenate(chunk_did)
-        dn = np.concatenate(chunk_dn)
-        wall = np.concatenate(chunk_wall)
-        group = np.concatenate(chunk_group)
-        src = np.concatenate(chunk_src)
-        # Stable sort by key: within a key, rows stay store-major in slot
-        # order — the same duplicate tie-break as the per-key merge.
-        order = np.argsort(group, kind="stable")
-        vv, did, dn, wall = vv[order], did[order], dn[order], wall[order]
-        group, src = group[order], src[order]
-        values = [values[int(i)] for i in order]
-        M = len(group)
-        counts = np.bincount(group, minlength=N)
-        starts = np.zeros(N + 1, np.int64)
-        np.cumsum(counts, out=starts[1:])
-        pos = np.arange(M) - starts[group]
-        K = int(counts.max(initial=1))
-        vvs = np.zeros((N, K, Ru), np.int32)
-        dids = np.full((N, K), NO_DOT, np.int32)
-        dns = np.zeros((N, K), np.int32)
-        valid = np.zeros((N, K), bool)
-        vvs[group, pos] = vv
-        dids[group, pos] = did
-        dns[group, pos] = dn
-        valid[group, pos] = True
+            vv = np.concatenate(chunk_vv)
+            did = np.concatenate(chunk_did)
+            dn = np.concatenate(chunk_dn)
+            wall = np.concatenate(chunk_wall)
+            group = np.concatenate(chunk_group)
+            src = np.concatenate(chunk_src)
+            # Stable sort by key: within a key, rows stay store-major in
+            # slot order — the same duplicate tie-break as the per-key
+            # merge.
+            order = np.argsort(group, kind="stable")
+            vv, did, dn, wall = vv[order], did[order], dn[order], wall[order]
+            group, src = group[order], src[order]
+            values = [values[int(i)] for i in order]
+            M = len(group)
+            counts = np.bincount(group, minlength=N)
+            starts = np.zeros(N + 1, np.int64)
+            np.cumsum(counts, out=starts[1:])
+            pos = np.arange(M) - starts[group]
+            K = int(counts.max(initial=1))
+            vvs = np.zeros((N, K, Ru), np.int32)
+            dids = np.full((N, K), NO_DOT, np.int32)
+            dns = np.zeros((N, K), np.int32)
+            valid = np.zeros((N, K), bool)
+            vvs[group, pos] = vv
+            dids[group, pos] = did
+            dns[group, pos] = dn
+            valid[group, pos] = True
         ceil = None
         if sweep_fn is not None:              # fused survival + ceilings
             mask, ceil = sweep_fn(vvs, dids, dns, valid)
             mask, ceil = np.asarray(mask), np.asarray(ceil)
         elif mask_fn is None:
-            mask = B.sync_mask_np(vvs, dids, dns, valid)
+            with trace.span(trace.PACKED_MASK):
+                mask = B.sync_mask_np(vvs, dids, dns, valid)
         else:
             mask = np.asarray(mask_fn(vvs, dids, dns, valid))
-        surv = mask[group, pos]
-        # One survivor gather for the whole group; per-key outputs are
-        # contiguous slices of it (rows are group-sorted already).
-        s_all = np.flatnonzero(surv)
-        vv_s, did_s, dn_s = vv[s_all], did[s_all], dn[s_all]
-        if ceil is None:
-            ceil = B.grouped_ceiling_np(vv_s, did_s, dn_s, group[s_all], N)
-        sb = np.zeros(N + 1, np.int64)
-        np.cumsum(np.bincount(group[s_all], minlength=N), out=sb[1:])
-        # plain-int views: the string/set building below is pure Python
-        s_list = s_all.tolist()
-        vv_l, did_l, dn_l = vv_s.tolist(), did_s.tolist(), dn_s.tolist()
-        wall_l = wall[s_all].tolist()
-        ceil_l = ceil.tolist()
-        sorted_cols = sorted((rid, c) for c, rid in enumerate(ids))
-        n_stores = len(stores)
-        ids_t = tuple(ids)
-        for g, key in enumerate(gkeys):
-            lo, hi = int(sb[g]), int(sb[g + 1])
-            stale: Tuple[int, ...] = ()
-            if track_stale:
-                surv_set = set()
-                member: List[set] = [set() for _ in range(n_stores)]
-                for i in range(int(starts[g]), int(starts[g + 1])):
-                    # row identity = clock AND value content: the
-                    # clock-equal/value-different state (§6.1 gap) must
-                    # flag as stale, never read as converged
-                    rk = (vv[i].tobytes(), int(did[i]), int(dn[i]),
-                          repr(values[i]))
-                    member[int(src[i])].add(rk)
-                    if surv[i]:
-                        surv_set.add(rk)
-                stale = tuple(j for j in range(n_stores)
-                              if member[j] != surv_set)
-            cg = ceil_l[g]
-            out[key] = MergedRead(
-                replica_ids=ids_t,
-                vv=vv_s[lo:hi],
-                dot_id=did_s[lo:hi],
-                dot_n=dn_s[lo:hi],
-                values=[values[i] for i in s_list[lo:hi]],
-                walls=wall_l[lo:hi],
-                clock_keys=[_clock_key(vv_l[i], did_l[i], dn_l[i],
-                                       sorted_cols) for i in range(lo, hi)],
-                entries=tuple(sorted(
-                    (ids_t[c], cg[c]) for c in range(Ru) if cg[c] > 0)),
-                stale=stale)
+        with trace.span(trace.PACKED_CEILING):
+            surv = mask[group, pos]
+            # One survivor gather for the whole group; per-key outputs are
+            # contiguous slices of it (rows are group-sorted already).
+            s_all = np.flatnonzero(surv)
+            vv_s, did_s, dn_s = vv[s_all], did[s_all], dn[s_all]
+            if ceil is None:
+                ceil = B.grouped_ceiling_np(vv_s, did_s, dn_s, group[s_all],
+                                            N)
+            sb = np.zeros(N + 1, np.int64)
+            np.cumsum(np.bincount(group[s_all], minlength=N), out=sb[1:])
+            # plain-int views: the string/set building below is pure
+            # Python
+            s_list = s_all.tolist()
+            vv_l, did_l, dn_l = vv_s.tolist(), did_s.tolist(), dn_s.tolist()
+            wall_l = wall[s_all].tolist()
+            ceil_l = ceil.tolist()
+            sorted_cols = sorted((rid, c) for c, rid in enumerate(ids))
+            n_stores = len(stores)
+            ids_t = tuple(ids)
+            for g, key in enumerate(gkeys):
+                lo, hi = int(sb[g]), int(sb[g + 1])
+                stale: Tuple[int, ...] = ()
+                if track_stale:
+                    surv_set = set()
+                    member: List[set] = [set() for _ in range(n_stores)]
+                    for i in range(int(starts[g]), int(starts[g + 1])):
+                        # row identity = clock AND value content: the
+                        # clock-equal/value-different state (§6.1 gap)
+                        # must flag as stale, never read as converged
+                        rk = (vv[i].tobytes(), int(did[i]), int(dn[i]),
+                              repr(values[i]))
+                        member[int(src[i])].add(rk)
+                        if surv[i]:
+                            surv_set.add(rk)
+                    stale = tuple(j for j in range(n_stores)
+                                  if member[j] != surv_set)
+                cg = ceil_l[g]
+                out[key] = MergedRead(
+                    replica_ids=ids_t,
+                    vv=vv_s[lo:hi],
+                    dot_id=did_s[lo:hi],
+                    dot_n=dn_s[lo:hi],
+                    values=[values[i] for i in s_list[lo:hi]],
+                    walls=wall_l[lo:hi],
+                    clock_keys=[_clock_key(vv_l[i], did_l[i], dn_l[i],
+                                           sorted_cols)
+                                for i in range(lo, hi)],
+                    entries=tuple(sorted(
+                        (ids_t[c], cg[c]) for c in range(Ru) if cg[c] > 0)),
+                    stale=stale)
     return out
 
 

@@ -62,6 +62,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, \
 
 import numpy as np
 
+from .. import trace
 from .client import KVClient
 from .cluster import GetResult, KVCluster, PutAck
 from .network import Unavailable
@@ -78,7 +79,8 @@ class PendingOp:
 
     __slots__ = ("kind", "keys", "items", "quorum", "repair", "client_id",
                  "client_counter", "session", "submitted_at", "completed_at",
-                 "_result", "error", "_callbacks", "_predicted_short")
+                 "_result", "error", "_callbacks", "_predicted_short",
+                 "enqueued_ns")
 
     def __init__(self, kind: str, keys: Tuple[str, ...], *,
                  items: Optional[Dict[str, Tuple[Any, Any]]] = None,
@@ -99,6 +101,7 @@ class PendingOp:
         self.error: Optional[Exception] = None
         self._callbacks: List[Callable[["PendingOp"], None]] = []
         self._predicted_short = False     # put: will miss its write quorum
+        self.enqueued_ns = 0              # wall ns at enqueue, when tracing
 
     @property
     def done(self) -> bool:
@@ -245,6 +248,8 @@ class OpScheduler:
         return KVClient(self.cluster, client_id, scheduler=self, **kw)
 
     def _enqueue(self, op: PendingOp) -> None:
+        if trace.active():
+            op.enqueued_ns = time.perf_counter_ns()
         self._queue.append(op)
         self.ops_submitted += 1
         if len(self._queue) >= self.max_batch and not self._in_flush:
@@ -292,10 +297,31 @@ class OpScheduler:
         self.flushes += 1
         self.flush_triggers[trigger] += 1
         self.largest_flush = max(self.largest_flush, len(ops))
+        if trace.active():
+            self._count_queue_wait(ops)
+        trace.set_flush(self.flushes)
+        try:
+            with trace.span(trace.SCHED_FLUSH):
+                self._serve_flush(ops)
+        finally:
+            trace.set_flush(0)
+
+    @staticmethod
+    def _count_queue_wait(ops: List[PendingOp]) -> None:
+        """Wall ns from each op's enqueue to the start of its flush, for
+        the ops that were stamped at enqueue (tracing on by then)."""
+        now = time.perf_counter_ns()
+        stamped = [op.enqueued_ns for op in ops if op.enqueued_ns]
+        trace.count(trace.SCHED_QUEUE_WAIT_NS,
+                    now * len(stamped) - sum(stamped))
+        trace.count(trace.SCHED_OPS_FLUSHED, len(stamped))
+
+    def _serve_flush(self, ops: List[PendingOp]) -> None:
         if self.pump:
             self.cluster.deliver_replication(until=self.network.now)
         proxy = self.via
-        admitted = self._admit(ops, proxy)
+        with trace.span(trace.SCHED_ADMIT):
+            admitted = self._admit(ops, proxy)
         # Snapshot ops run as their own phase FIRST: they read at the
         # Global Stable Frontier, and this flush's puts cannot lift it —
         # their replication messages / WAN backlog entries are obligations
@@ -307,19 +333,22 @@ class OpScheduler:
             self.phases_run += 1
             self._run_snapshot_phase(snaps, proxy)
             admitted = [op for op in admitted if op.kind != "snapshot"]
-        for kind, phase_ops in self._plan(admitted):
+        with trace.span(trace.SCHED_PLAN):
+            plan = self._plan(admitted)
+        for kind, phase_ops in plan:
             self.phases_run += 1
             if kind == "get":
                 self._run_get_phase(phase_ops, proxy)
             else:
                 self._run_put_phase(phase_ops, proxy)
         now = self.network.now
-        for op in ops:                   # completion in submission order
-            if op.error is None:
-                self.ops_ok += 1
-            else:
-                self.ops_failed += 1
-            op._complete(now)
+        with trace.span(trace.SCHED_COMPLETE):
+            for op in ops:               # completion in submission order
+                if op.error is None:
+                    self.ops_ok += 1
+                else:
+                    self.ops_failed += 1
+                op._complete(now)
 
     def _admit(self, ops: List[PendingOp], proxy: str) -> List[PendingOp]:
         """Per-op triage via the cluster's non-raising probes; failed ops
