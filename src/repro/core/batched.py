@@ -309,6 +309,14 @@ def bucket_shape(n: int, k: int, r: int, *, min_n: int = 8, min_k: int = 2,
             max(min_r, _ceil_pow2(r)))
 
 
+#: Most keys (rows of a grouped ``[N, K, R]`` tensor) one stacked kernel
+#: launch carries (``store.packed.stacked_launches``).  The kernels pad keys
+#: to a 128-lane block anyway, so a launch of 32 keys costs the device what
+#: one of 8 does, and every stack still lands in one of a few ``N`` buckets
+#: (8, 16, 32) a caller can compile ahead.
+STACK_ROWS = 32
+
+
 def pad_sync_args(vvs: np.ndarray, dot_ids: np.ndarray, dot_ns: np.ndarray,
                   valid: np.ndarray, shape: Tuple[int, int, int]):
     """Zero/NO_DOT/False-pad a grouped sync tensor up to ``shape``."""

@@ -31,7 +31,7 @@ from .context import CausalContext
 from .network import SimNetwork, Unavailable
 from .packed import MergedRead, NO_DOT, PackedPayload, quorum_merge_key, \
     quorum_merge_many, remap_rows
-from .replica import ReplicaNode
+from .replica import ReplicaNode, coordinate_many
 from .sharding import DEFAULT_PLACEMENT_SLICES, DEFAULT_VNODES, HashRing, \
     key_hash64, moved_shards, owned_shards, shard_of_key
 from .version import HybridClock, Version, clocks_of, sync_versions
@@ -733,7 +733,9 @@ class KVCluster:
                 sweep_fn = dvv_read_sweep_bucketed
             # Stores are per-(node, shard): quorum_merge_many's grouping by
             # store-identity tuple therefore fans the sweep out per
-            # (shard, quorum-group) — each group one stacked tensor.
+            # (shard, quorum-group) — each group one tensor, and on the
+            # kernel all groups' tensors share launches of up to
+            # STACK_ROWS keys.
             merged = quorum_merge_many(
                 {k: [self.nodes[r].store_for(k) for r in chosen[k]]
                  for k in packed_keys},
@@ -901,7 +903,10 @@ class KVCluster:
         as ONE vectorized store update (one grouped encode → one
         ``sync_mask`` sweep → one scatter) and ONE replication payload per
         destination replica, instead of K independent ``sync_key`` walks
-        and K·(R−1) messages.  Admission is atomic: if any key has no
+        and K·(R−1) messages.  Every coordinator's groups are updated
+        first (``replica.coordinate_many``; with ``use_kernel=True`` all
+        their sync masks share launches of up to ``STACK_ROWS`` keys),
+        then each coordinator replicates in turn.  Admission is atomic: if any key has no
         reachable coordinator, nothing is written.  Writes are always
         durable at their coordinators; if any key then misses its write
         quorum, ``Unavailable`` is raised after the batch is applied
@@ -939,16 +944,22 @@ class KVCluster:
         if use_kernel:
             from ..kernels.dvv_ops import dvv_sync_mask_bucketed
             mask_fn = dvv_sync_mask_bucketed
+        # Every coordinator's updates, all shards, before any replication:
+        # each (node, shard) store is distinct and replication only queues
+        # messages, so this order changes no result, and on the kernel all
+        # their sync masks share launches of up to STACK_ROWS keys.
+        with trace.span(trace.PLANE_PUT_UPDATE):
+            coordinated = coordinate_many(
+                [(self.nodes[coord],
+                  [(k, ctxs[k], items[k][0], walls[k]) for k in keys])
+                 for coord, keys in groups.items()],
+                client_id=client_id, client_counter=client_counter,
+                mask_fn=mask_fn)
         geo = self.geo
-        for coord, keys in groups.items():
+        for (coord, keys), versions in zip(groups.items(), coordinated):
             self.plane_writes += 1
             cdc = geo.dc_of[coord] if geo is not None else None
             node = self.nodes[coord]
-            with trace.span(trace.PLANE_PUT_UPDATE):
-                batch = [(k, ctxs[k], items[k][0], walls[k]) for k in keys]
-                versions = node.coordinate_updates(
-                    batch, client_id=client_id,
-                    client_counter=client_counter, mask_fn=mask_fn)
             for k, v in zip(keys, versions):
                 minted[k] = v
                 acked[k] = [coord]

@@ -323,6 +323,27 @@ def split_payload(payload: PackedPayload, shards: int
     return out
 
 
+@dataclass
+class StagedPayload:
+    """A payload staged against one store (``PackedVersionStore.
+    stage_payload``): the grouped clock tensor ``(vvs[N, K, R], dot_ids,
+    dot_ns, valid)`` whose survival mask ``commit_payload`` takes, and the
+    plan of the write-back — the payload's rows in local columns and where
+    each incoming and resident row sits in the tensor."""
+
+    payload: PackedPayload
+    key_ixs: np.ndarray        # int64[N] the store's key of each group
+    before_sets: Optional[List[FrozenSet[Version]]]
+    inc_vv: np.ndarray         # int32[M, R] incoming rows, local columns
+    inc_did: np.ndarray        # int32[M]
+    inc_group: np.ndarray      # int64[M] group of each incoming row
+    inc_pos: np.ndarray        # int64[M] its position in the group
+    loc_rows: np.ndarray       # int64[L] resident slots of the groups
+    loc_group: np.ndarray
+    loc_pos: np.ndarray
+    tensor: Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
+
+
 class PackedVersionStore:
     """The resident packed store.  All mutation is numpy; bulk merges hand
     one [N, K, R] tensor to ``core.batched.sync_mask`` or the fused Pallas
@@ -898,12 +919,12 @@ class PackedVersionStore:
         return vv, r_ix, dot_n
 
     def update_keys(self, updates: Sequence[Tuple[str, Iterable[Tuple[str,
-                    int]], Any, float]], coordinator: str, *,
-                    mask_fn=None) -> Tuple[np.ndarray, int, np.ndarray]:
+                    int]], Any, float]], coordinator: str
+                    ) -> Tuple[np.ndarray, int, np.ndarray]:
         """Batched §5.3 update: mint one clock per key, then merge all of
         them with ONE grouped ``apply_payload`` pass (one scatter, one
-        ``sync_mask`` evaluation — optionally the shape-bucketed jit/Pallas
-        cache via ``mask_fn``) instead of K independent ``sync_key`` walks.
+        ``sync_mask`` evaluation) instead of K independent ``sync_key``
+        walks.
 
         ``updates`` is ``[(key, ceiling_entries, value, wall), ...]`` with
         *distinct* keys (a batch is a set of independent writes; two writes
@@ -911,6 +932,16 @@ class PackedVersionStore:
         Returns ``(vv[M, R], r_ix, dot_n[M])`` for the minted clocks,
         aligned with ``updates``.
         """
+        minted, vv, r_ix, dot_n = self.mint_updates(updates, coordinator)
+        self.apply_payload(minted)
+        return vv, r_ix, dot_n
+
+    def mint_updates(self, updates: Sequence[Tuple[str, Iterable[Tuple[str,
+                     int]], Any, float]], coordinator: str
+                     ) -> Tuple[PackedPayload, np.ndarray, int, np.ndarray]:
+        """The minting half of ``update_keys``: the new clocks as a payload
+        to apply, and ``(vv, r_ix, dot_n)`` as ``update_keys`` returns
+        them.  Grows the universe and interns the keys; touches no slot."""
         with trace.span(trace.PACKED_GATHER):
             keys = [u[0] for u in updates]
             if len(set(keys)) != len(keys):
@@ -944,8 +975,7 @@ class PackedVersionStore:
                 key_ix=np.arange(M, dtype=np.int32),
                 values=tuple(u[2] for u in updates),
                 wall=np.asarray([u[3] for u in updates], np.float64))
-        self.apply_payload(minted, mask_fn=mask_fn)
-        return vv, r_ix, dot_n
+        return minted, vv, r_ix, dot_n
 
     def context_ceiling(self, context: Iterable[DVV]) -> np.ndarray:
         """⌈S⌉ of a client context (object clocks — the API edge), in local
@@ -1052,11 +1082,30 @@ class PackedVersionStore:
 
         Fully vectorized: grouping is one stable sort + two fancy-index
         scatters; write-back is one masked kill + one bulk append.  No
-        per-key DVV objects, no per-key numpy calls.
+        per-key DVV objects, no per-key numpy calls.  The two halves are
+        ``stage_payload`` and ``commit_payload``, which a caller stacking
+        several stores' masks into shared launches calls itself.
         """
+        staged = self.stage_payload(payload)
+        if staged is None:
+            return 0
+        if mask_fn is None:
+            with trace.span(trace.PACKED_MASK):
+                mask = B.sync_mask_np(*staged.tensor)
+        else:
+            mask = np.asarray(mask_fn(*staged.tensor))
+        return self.commit_payload(staged, mask)
+
+    def stage_payload(self, payload: PackedPayload
+                      ) -> Optional["StagedPayload"]:
+        """The gather half of ``apply_payload``: remap the payload into the
+        local universe and stack it with the resident slots of its keys
+        into one grouped ``[N, K, R]`` tensor, with the plan of the
+        write-back.  Touches no slot; ``None`` for an empty payload.  The
+        store must not change between this and ``commit_payload``."""
         M = len(payload)
         if M == 0:
-            return 0
+            return None
         with trace.span(trace.PACKED_GATHER):
             inc_vv, inc_did = self._remap_columns(payload)
             inc_dn = payload.dot_n
@@ -1111,12 +1160,26 @@ class PackedVersionStore:
             dns[inc_group, inc_pos] = inc_dn
             valid[inc_group, inc_pos] = True
 
-        if mask_fn is None:
-            with trace.span(trace.PACKED_MASK):
-                mask = B.sync_mask_np(vvs, dids, dns, valid)
-        else:
-            mask = np.asarray(mask_fn(vvs, dids, dns, valid))
+        return StagedPayload(
+            payload=payload, key_ixs=key_ixs, before_sets=before_sets,
+            inc_vv=inc_vv, inc_did=inc_did, inc_group=inc_group,
+            inc_pos=inc_pos, loc_rows=loc_rows, loc_group=loc_group,
+            loc_pos=loc_pos, tensor=(vvs, dids, dns, valid))
 
+    def commit_payload(self, staged: "StagedPayload", mask: np.ndarray
+                       ) -> int:
+        """The write-back half of ``apply_payload``: kill the resident
+        slots ``mask`` (bool ``[N, K]`` over the staged tensor) drops and
+        append the incoming rows it keeps.  Returns the number of keys
+        whose version set changed."""
+        payload, key_ixs = staged.payload, staged.key_ixs
+        inc_vv, inc_did = staged.inc_vv, staged.inc_did
+        inc_dn = payload.dot_n
+        inc_group, inc_pos = staged.inc_group, staged.inc_pos
+        loc_rows, loc_group = staged.loc_rows, staged.loc_group
+        loc_pos, before_sets = staged.loc_pos, staged.before_sets
+        N = len(key_ixs)
+        R = inc_vv.shape[1]
         with trace.span(trace.PACKED_SCATTER):
             # -- write-back: masked kill of local slots --------------------
             changed_groups = np.zeros(N, bool)
@@ -1230,6 +1293,109 @@ class PackedVersionStore:
 
 
 # ---------------------------------------------------------------------------
+# Stacked kernel launches — many grouped tensors, one device call per chunk.
+# ---------------------------------------------------------------------------
+
+ClockTensor = Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
+
+
+def _stack_rows(tensors: Sequence[ClockTensor],
+                parts: Sequence[Tuple[int, int, int]]) -> ClockTensor:
+    """Rows ``lo:hi`` of each ``(tensor, lo, hi)`` part, concatenated along
+    ``N`` and padded with empty ranges, ``NO_DOT`` and ``valid`` False to
+    the parts' largest ``K`` and ``R``."""
+    K = max(tensors[i][0].shape[1] for i, _, _ in parts)
+    R = max(tensors[i][0].shape[2] for i, _, _ in parts)
+    N = sum(hi - lo for _, lo, hi in parts)
+    out = (np.zeros((N, K, R), np.int32), np.full((N, K), NO_DOT, np.int32),
+           np.zeros((N, K), np.int32), np.zeros((N, K), bool))
+    off = 0
+    for i, lo, hi in parts:
+        vvs = tensors[i][0]
+        k, r = vvs.shape[1], vvs.shape[2]
+        m = hi - lo
+        out[0][off: off + m, :k, :r] = vvs[lo:hi]
+        for dst, src in zip(out[1:], tensors[i][1:]):
+            dst[off: off + m, :k] = src[lo:hi]
+        off += m
+    return out
+
+
+def stacked_launches(fn, tensors: Sequence[ClockTensor], *,
+                     ceilings: bool = False) -> List[Any]:
+    """Evaluate ``fn`` over independent grouped clock tensors in shared
+    launches of at most ``STACK_ROWS`` keys.
+
+    ``tensors`` are ``(vvs[Ni, Ki, Ri], dot_ids, dot_ns, valid)`` tuples,
+    each in a replica universe of its own (quorum groups, per-shard write
+    batches).  Their rows are concatenated along ``N`` and cut into chunks
+    of ``STACK_ROWS``; each chunk is padded to the largest ``K`` and ``R``
+    among its rows and handed to ``fn`` once.  That is exact: neither the
+    survival mask nor the ceiling computes anything across rows, a zero
+    replica column is an empty range, and a row with ``valid`` False is
+    inert (the padding of ``core.batched.bucket_shape``), so a row's mask
+    and ceiling do not depend on what it is stacked with.
+
+    ``fn`` is a ``mask_fn`` (→ bool ``[N, K]``) or, with ``ceilings``, a
+    ``sweep_fn`` (→ ``(mask, ceil [N, R])``).  Returns per tensor its mask
+    ``[Ni, Ki]``, or ``(mask, ceil int64[Ni, Ri])`` pairs.  Counts
+    ``plane.stack.tensors`` and ``plane.stack.launches`` while tracing,
+    and the clock cells (``N*K*R``) of the tensors given and of the
+    launches made, whose ratio is what the stacking pads.
+    """
+    masks = [np.zeros(t[0].shape[:2], bool) for t in tensors]
+    ceils = [np.zeros((t[0].shape[0], t[0].shape[2]), np.int64)
+             for t in tensors] if ceilings else []
+    chunks: List[List[Tuple[int, int, int]]] = []
+    room = 0
+    for i, t in enumerate(tensors):
+        n, lo = t[0].shape[0], 0
+        while lo < n:
+            if not room:
+                chunks.append([])
+                room = B.STACK_ROWS
+            take = min(room, n - lo)
+            chunks[-1].append((i, lo, lo + take))
+            room -= take
+            lo += take
+    launched = 0
+    for parts in chunks:
+        i0, lo0, hi0 = parts[0]
+        if len(parts) == 1 and hi0 - lo0 == len(masks[i0]):
+            args = tensors[i0]              # one whole tensor: no copy
+        else:
+            args = _stack_rows(tensors, parts)
+        launched += args[0].size
+        out = fn(*args)
+        mask, ceil = (np.asarray(out[0]), np.asarray(out[1])) if ceilings \
+            else (np.asarray(out), None)
+        off = 0
+        for i, lo, hi in parts:
+            k, r = masks[i].shape[1], tensors[i][0].shape[2]
+            m = hi - lo
+            masks[i][lo:hi] = mask[off: off + m, :k]
+            if ceilings:
+                ceils[i][lo:hi] = ceil[off: off + m, :r]
+            off += m
+    trace.count(trace.PLANE_STACK_TENSORS, sum(1 for m in masks if len(m)))
+    trace.count(trace.PLANE_STACK_LAUNCHES, len(chunks))
+    trace.count(trace.PLANE_STACK_CELLS, sum(t[0].size for t in tensors))
+    trace.count(trace.PLANE_STACK_LAUNCHED_CELLS, launched)
+    return list(zip(masks, ceils)) if ceilings else masks
+
+
+def sync_masks(tensors: Sequence[ClockTensor], mask_fn=None
+               ) -> List[np.ndarray]:
+    """Survival masks of independent grouped clock tensors, aligned with
+    ``tensors``: on a device ``mask_fn`` in ``stacked_launches``, else the
+    numpy reference tensor by tensor."""
+    if mask_fn is not None:
+        return stacked_launches(mask_fn, tensors)
+    with trace.span(trace.PACKED_MASK):
+        return [B.sync_mask_np(*t) for t in tensors]
+
+
+# ---------------------------------------------------------------------------
 # Quorum GET merge — arrays across stores, zero object-clock decodes.
 # ---------------------------------------------------------------------------
 
@@ -1303,8 +1469,10 @@ def quorum_merge_many(stores_by_key: Mapping[str,
     reduce.  ``sweep_fn`` (wins over ``mask_fn``) fuses both steps on
     device — a ``(vvs, dids, dns, valid) → (mask, ceil)`` callable like
     ``kernels.dvv_ops.dvv_read_sweep_bucketed``, the path
-    ``use_kernel=True`` reads take.  No ``DVV`` object is created
-    anywhere.
+    ``use_kernel=True`` reads take.  Every group is gathered first; on
+    either callable all groups' tensors then share launches of up to
+    ``STACK_ROWS`` keys (``stacked_launches``), and each group's result is
+    built in group order.  No ``DVV`` object is created anywhere.
 
     Returns ``{key: MergedRead}`` — survivors plus the per-member staleness
     signal read-repair consumes (``track_stale=False`` skips that
@@ -1322,141 +1490,175 @@ def quorum_merge_many(stores_by_key: Mapping[str,
     for k in keys:
         groups.setdefault(
             tuple(id(st) for st in stores_by_key[k]), []).append(k)
-    for gkeys in groups.values():
-        with trace.span(trace.PACKED_GATHER):
-            stores = list(stores_by_key[gkeys[0]])
-            N = len(gkeys)
-            # Union replica universe + per-store column maps, built ONCE
-            # per group — the per-key rebuild was the looped read path's
-            # tax.
-            ids: List[str] = []
-            index: Dict[str, int] = {}
-            col_maps: List[np.ndarray] = []
-            for st in stores:
-                cols = np.empty(st.n_replicas, np.int64)
-                for j, rid in enumerate(st.replica_ids):
-                    ix = index.get(rid)
-                    if ix is None:
-                        ix = index[rid] = len(ids)
-                        ids.append(rid)
-                    cols[j] = ix
-                col_maps.append(cols)
-            Ru = len(ids)
-            # One gather per store: all of its rows for all group keys at
-            # once.
-            chunk_vv, chunk_did, chunk_dn, chunk_wall = [], [], [], []
-            chunk_group, chunk_src = [], []
-            values: List[Any] = []
-            for j, (st, cols) in enumerate(zip(stores, col_maps)):
-                lists = [st.key_slots(k) for k in gkeys]
-                rows = np.asarray([s for l in lists for s in l], np.int64)
-                if not len(rows):
-                    continue
-                cv, cdid = remap_rows(st.vv[rows, : st.n_replicas],
-                                      st.dot_id[rows], cols, Ru)
-                chunk_vv.append(cv)
-                chunk_did.append(cdid)
-                chunk_dn.append(st.dot_n[rows])
-                chunk_wall.append(st.wall[rows])
-                chunk_group.append(
-                    np.repeat(np.arange(N), [len(l) for l in lists]))
-                chunk_src.append(np.full(len(rows), j, np.int64))
-                values.extend(st.values[int(s)] for s in rows)
-            if not chunk_vv:                  # no store holds any group key
-                for key in gkeys:
-                    out[key] = MergedRead(
-                        tuple(ids), np.zeros((0, Ru), np.int32),
-                        np.zeros(0, np.int32), np.zeros(0, np.int32),
-                        [], [], [], ())
-                continue
-            vv = np.concatenate(chunk_vv)
-            did = np.concatenate(chunk_did)
-            dn = np.concatenate(chunk_dn)
-            wall = np.concatenate(chunk_wall)
-            group = np.concatenate(chunk_group)
-            src = np.concatenate(chunk_src)
-            # Stable sort by key: within a key, rows stay store-major in
-            # slot order — the same duplicate tie-break as the per-key
-            # merge.
-            order = np.argsort(group, kind="stable")
-            vv, did, dn, wall = vv[order], did[order], dn[order], wall[order]
-            group, src = group[order], src[order]
-            values = [values[int(i)] for i in order]
-            M = len(group)
-            counts = np.bincount(group, minlength=N)
-            starts = np.zeros(N + 1, np.int64)
-            np.cumsum(counts, out=starts[1:])
-            pos = np.arange(M) - starts[group]
-            K = int(counts.max(initial=1))
-            vvs = np.zeros((N, K, Ru), np.int32)
-            dids = np.full((N, K), NO_DOT, np.int32)
-            dns = np.zeros((N, K), np.int32)
-            valid = np.zeros((N, K), bool)
-            vvs[group, pos] = vv
-            dids[group, pos] = did
-            dns[group, pos] = dn
-            valid[group, pos] = True
-        ceil = None
-        if sweep_fn is not None:              # fused survival + ceilings
-            mask, ceil = sweep_fn(vvs, dids, dns, valid)
-            mask, ceil = np.asarray(mask), np.asarray(ceil)
-        elif mask_fn is None:
-            with trace.span(trace.PACKED_MASK):
-                mask = B.sync_mask_np(vvs, dids, dns, valid)
-        else:
-            mask = np.asarray(mask_fn(vvs, dids, dns, valid))
-        with trace.span(trace.PACKED_CEILING):
-            surv = mask[group, pos]
-            # One survivor gather for the whole group; per-key outputs are
-            # contiguous slices of it (rows are group-sorted already).
-            s_all = np.flatnonzero(surv)
-            vv_s, did_s, dn_s = vv[s_all], did[s_all], dn[s_all]
-            if ceil is None:
-                ceil = B.grouped_ceiling_np(vv_s, did_s, dn_s, group[s_all],
-                                            N)
-            sb = np.zeros(N + 1, np.int64)
-            np.cumsum(np.bincount(group[s_all], minlength=N), out=sb[1:])
-            # plain-int views: the string/set building below is pure
-            # Python
-            s_list = s_all.tolist()
-            vv_l, did_l, dn_l = vv_s.tolist(), did_s.tolist(), dn_s.tolist()
-            wall_l = wall[s_all].tolist()
-            ceil_l = ceil.tolist()
-            sorted_cols = sorted((rid, c) for c, rid in enumerate(ids))
-            n_stores = len(stores)
-            ids_t = tuple(ids)
-            for g, key in enumerate(gkeys):
-                lo, hi = int(sb[g]), int(sb[g + 1])
-                stale: Tuple[int, ...] = ()
-                if track_stale:
-                    surv_set = set()
-                    member: List[set] = [set() for _ in range(n_stores)]
-                    for i in range(int(starts[g]), int(starts[g + 1])):
-                        # row identity = clock AND value content: the
-                        # clock-equal/value-different state (§6.1 gap)
-                        # must flag as stale, never read as converged
-                        rk = (vv[i].tobytes(), int(did[i]), int(dn[i]),
-                              repr(values[i]))
-                        member[int(src[i])].add(rk)
-                        if surv[i]:
-                            surv_set.add(rk)
-                    stale = tuple(j for j in range(n_stores)
-                                  if member[j] != surv_set)
-                cg = ceil_l[g]
-                out[key] = MergedRead(
-                    replica_ids=ids_t,
-                    vv=vv_s[lo:hi],
-                    dot_id=did_s[lo:hi],
-                    dot_n=dn_s[lo:hi],
-                    values=[values[i] for i in s_list[lo:hi]],
-                    walls=wall_l[lo:hi],
-                    clock_keys=[_clock_key(vv_l[i], did_l[i], dn_l[i],
-                                           sorted_cols)
-                                for i in range(lo, hi)],
-                    entries=tuple(sorted(
-                        (ids_t[c], cg[c]) for c in range(Ru) if cg[c] > 0)),
-                    stale=stale)
+    gathered = [_gather_quorum_group(list(stores_by_key[gkeys[0]]), gkeys)
+                for gkeys in groups.values()]
+    tensors = [g.tensor for g in gathered if g.tensor is not None]
+    if sweep_fn is not None:                  # fused survival + ceilings
+        results = iter(stacked_launches(sweep_fn, tensors, ceilings=True))
+    else:
+        results = ((m, None) for m in sync_masks(tensors, mask_fn))
+    for g in gathered:
+        mask, ceil = next(results) if g.tensor is not None else (None, None)
+        _finish_quorum_group(g, mask, ceil, track_stale, out)
     return out
+
+
+@dataclass
+class _QuorumGroup:
+    """One quorum group's rows, gathered into its union universe: the
+    group-sorted rows, their values and sources, and the grouped tensor
+    (``None`` when no store holds any of the group's keys)."""
+
+    gkeys: List[str]
+    n_stores: int
+    ids: List[str]
+    vv: Optional[np.ndarray] = None
+    did: Optional[np.ndarray] = None
+    dn: Optional[np.ndarray] = None
+    wall: Optional[np.ndarray] = None
+    group: Optional[np.ndarray] = None
+    src: Optional[np.ndarray] = None
+    values: Optional[List[Any]] = None
+    starts: Optional[np.ndarray] = None
+    pos: Optional[np.ndarray] = None
+    tensor: Optional[ClockTensor] = None
+
+
+def _gather_quorum_group(stores: List[PackedVersionStore],
+                         gkeys: List[str]) -> _QuorumGroup:
+    """The gather phase of ``quorum_merge_many`` for one group."""
+    with trace.span(trace.PACKED_GATHER):
+        N = len(gkeys)
+        # Union replica universe + per-store column maps, built ONCE per
+        # group — the per-key rebuild was the looped read path's tax.
+        ids: List[str] = []
+        index: Dict[str, int] = {}
+        col_maps: List[np.ndarray] = []
+        for st in stores:
+            cols = np.empty(st.n_replicas, np.int64)
+            for j, rid in enumerate(st.replica_ids):
+                ix = index.get(rid)
+                if ix is None:
+                    ix = index[rid] = len(ids)
+                    ids.append(rid)
+                cols[j] = ix
+            col_maps.append(cols)
+        Ru = len(ids)
+        # One gather per store: all of its rows for all group keys at once.
+        chunk_vv, chunk_did, chunk_dn, chunk_wall = [], [], [], []
+        chunk_group, chunk_src = [], []
+        values: List[Any] = []
+        for j, (st, cols) in enumerate(zip(stores, col_maps)):
+            lists = [st.key_slots(k) for k in gkeys]
+            rows = np.asarray([s for l in lists for s in l], np.int64)
+            if not len(rows):
+                continue
+            cv, cdid = remap_rows(st.vv[rows, : st.n_replicas],
+                                  st.dot_id[rows], cols, Ru)
+            chunk_vv.append(cv)
+            chunk_did.append(cdid)
+            chunk_dn.append(st.dot_n[rows])
+            chunk_wall.append(st.wall[rows])
+            chunk_group.append(
+                np.repeat(np.arange(N), [len(l) for l in lists]))
+            chunk_src.append(np.full(len(rows), j, np.int64))
+            values.extend(st.values[int(s)] for s in rows)
+        g = _QuorumGroup(gkeys, len(stores), ids)
+        if not chunk_vv:                      # no store holds any group key
+            return g
+        vv = np.concatenate(chunk_vv)
+        did = np.concatenate(chunk_did)
+        dn = np.concatenate(chunk_dn)
+        wall = np.concatenate(chunk_wall)
+        group = np.concatenate(chunk_group)
+        src = np.concatenate(chunk_src)
+        # Stable sort by key: within a key, rows stay store-major in slot
+        # order — the same duplicate tie-break as the per-key merge.
+        order = np.argsort(group, kind="stable")
+        g.vv, g.did, g.dn = vv[order], did[order], dn[order]
+        g.wall, g.group, g.src = wall[order], group[order], src[order]
+        g.values = [values[int(i)] for i in order]
+        M = len(g.group)
+        counts = np.bincount(g.group, minlength=N)
+        g.starts = np.zeros(N + 1, np.int64)
+        np.cumsum(counts, out=g.starts[1:])
+        g.pos = np.arange(M) - g.starts[g.group]
+        K = int(counts.max(initial=1))
+        vvs = np.zeros((N, K, Ru), np.int32)
+        dids = np.full((N, K), NO_DOT, np.int32)
+        dns = np.zeros((N, K), np.int32)
+        valid = np.zeros((N, K), bool)
+        vvs[g.group, g.pos] = g.vv
+        dids[g.group, g.pos] = g.did
+        dns[g.group, g.pos] = g.dn
+        valid[g.group, g.pos] = True
+        g.tensor = (vvs, dids, dns, valid)
+    return g
+
+
+def _finish_quorum_group(g: _QuorumGroup, mask: Optional[np.ndarray],
+                         ceil: Optional[np.ndarray], track_stale: bool,
+                         out: Dict[str, MergedRead]) -> None:
+    """The ceiling and result phase of ``quorum_merge_many`` for one
+    group, given its survival mask (and its ceilings, from a fused
+    sweep)."""
+    ids_t = tuple(g.ids)
+    Ru = len(ids_t)
+    if g.tensor is None:
+        for key in g.gkeys:
+            out[key] = MergedRead(
+                ids_t, np.zeros((0, Ru), np.int32), np.zeros(0, np.int32),
+                np.zeros(0, np.int32), [], [], [], ())
+        return
+    N = len(g.gkeys)
+    vv, did, dn, group, values = g.vv, g.did, g.dn, g.group, g.values
+    with trace.span(trace.PACKED_CEILING):
+        surv = mask[group, g.pos]
+        # One survivor gather for the whole group; per-key outputs are
+        # contiguous slices of it (rows are group-sorted already).
+        s_all = np.flatnonzero(surv)
+        vv_s, did_s, dn_s = vv[s_all], did[s_all], dn[s_all]
+        if ceil is None:
+            ceil = B.grouped_ceiling_np(vv_s, did_s, dn_s, group[s_all], N)
+        sb = np.zeros(N + 1, np.int64)
+        np.cumsum(np.bincount(group[s_all], minlength=N), out=sb[1:])
+        # plain-int views: the string/set building below is pure Python
+        s_list = s_all.tolist()
+        vv_l, did_l, dn_l = vv_s.tolist(), did_s.tolist(), dn_s.tolist()
+        wall_l = g.wall[s_all].tolist()
+        ceil_l = ceil.tolist()
+        sorted_cols = sorted((rid, c) for c, rid in enumerate(ids_t))
+        for gi, key in enumerate(g.gkeys):
+            lo, hi = int(sb[gi]), int(sb[gi + 1])
+            stale: Tuple[int, ...] = ()
+            if track_stale:
+                surv_set = set()
+                member: List[set] = [set() for _ in range(g.n_stores)]
+                for i in range(int(g.starts[gi]), int(g.starts[gi + 1])):
+                    # row identity = clock AND value content: the
+                    # clock-equal/value-different state (§6.1 gap) must
+                    # flag as stale, never read as converged
+                    rk = (vv[i].tobytes(), int(did[i]), int(dn[i]),
+                          repr(values[i]))
+                    member[int(g.src[i])].add(rk)
+                    if surv[i]:
+                        surv_set.add(rk)
+                stale = tuple(j for j in range(g.n_stores)
+                              if member[j] != surv_set)
+            cg = ceil_l[gi]
+            out[key] = MergedRead(
+                replica_ids=ids_t,
+                vv=vv_s[lo:hi],
+                dot_id=did_s[lo:hi],
+                dot_n=dn_s[lo:hi],
+                values=[values[i] for i in s_list[lo:hi]],
+                walls=wall_l[lo:hi],
+                clock_keys=[_clock_key(vv_l[i], did_l[i], dn_l[i],
+                                       sorted_cols)
+                            for i in range(lo, hi)],
+                entries=tuple(sorted(
+                    (ids_t[c], cg[c]) for c in range(Ru) if cg[c] > 0)),
+                stale=stale)
 
 
 def quorum_merge_key(stores: Sequence[PackedVersionStore], key: str

@@ -14,14 +14,16 @@ Two storage backends implement the same node-local surface:
 """
 from __future__ import annotations
 
-from typing import Any, Dict, FrozenSet, Iterable, List, Optional, Sequence, \
-    Tuple, Union
+from typing import Any, Dict, FrozenSet, Iterable, List, NamedTuple, \
+    Optional, Sequence, Tuple, Union
+
+import numpy as np
 
 from ..core import batched as B
 from ..core.kernel import Mechanism
 from .context import CausalContext
 from .packed import DIGEST_BUCKETS, PackedPayload, PackedVersionStore, \
-    concat_payloads, split_payload
+    StagedPayload, concat_payloads, split_payload, sync_masks
 from .sharding import shard_of_key
 from .version import Version, clocks_of, sync_versions
 
@@ -29,6 +31,17 @@ Payload = Union[Dict[str, FrozenSet[Version]], PackedPayload]
 
 #: One batched write: (key, context token, value, wall_time).
 UpdateBatch = Sequence[Tuple[str, CausalContext, Any, float]]
+
+
+class StagedShard(NamedTuple):
+    """One shard's part of a staged write batch (``PackedBackend.
+    stage_updates``): its store, the batch indices it holds, the minted
+    ``(vv, r_ix, dot_n)`` and the payload staged against the store."""
+
+    store: PackedVersionStore
+    idxs: List[int]
+    minted: Tuple[np.ndarray, int, np.ndarray]
+    payload: StagedPayload
 
 
 class ObjectBackend:
@@ -181,24 +194,38 @@ class PackedBackend:
                          store.replica_ids)
         return Version(clock, value, wall=wall_time)
 
-    def coordinate_updates(self, batch: UpdateBatch, *,
-                           mask_fn=None) -> List[Version]:
-        """Batched §5.3 updates over distinct keys: one grouped encode →
-        one vectorized update → one scatter (``PackedVersionStore.
-        update_keys``) *per shard touched*, instead of K independent
-        ``sync_key`` walks.  Results come back in batch order."""
+    def stage_updates(self, batch: UpdateBatch) -> List[StagedShard]:
+        """Batched §5.3 updates over distinct keys, first half: mint and
+        stage each shard's updates as one grouped tensor (``Packed
+        VersionStore.mint_updates`` + ``stage_payload``), touching no
+        slot.  Shards in first-touch order."""
         groups: Dict[int, List[int]] = {}
         for i, (key, _, _, _) in enumerate(batch):
             groups.setdefault(shard_of_key(key, self.shards), []).append(i)
-        out: List[Optional[Version]] = [None] * len(batch)
+        staged = []
         for s, idxs in groups.items():
             store = self.stores[s]
-            items = [(batch[i][0], batch[i][1].ceiling_items(),
-                      batch[i][2], batch[i][3]) for i in idxs]
-            vv, r_ix, dot_n = store.update_keys(
-                items, self.node_id, mask_fn=mask_fn)
+            minted, vv, r_ix, dot_n = store.mint_updates(
+                [(batch[i][0], batch[i][1].ceiling_items(), batch[i][2],
+                  batch[i][3]) for i in idxs], self.node_id)
+            staged.append(StagedShard(store, idxs, (vv, r_ix, dot_n),
+                                      store.stage_payload(minted)))
+        return staged
+
+    def commit_updates(self, batch: UpdateBatch,
+                       staged: Sequence[StagedShard],
+                       masks: Sequence[np.ndarray]) -> List[Version]:
+        """The second half: write back each staged shard under its
+        survival mask (aligned with ``staged``), one scatter per shard,
+        and decode the freshly minted clocks for the ``PutAck``s (edge
+        decode); results in batch order."""
+        out: List[Optional[Version]] = [None] * len(batch)
+        for sh, mask in zip(staged, masks):
+            store = sh.store
+            store.commit_payload(sh.payload, mask)
+            vv, r_ix, dot_n = sh.minted
             R = store.n_replicas
-            for j, i in enumerate(idxs):
+            for j, i in enumerate(sh.idxs):
                 out[i] = Version(
                     B.decode(vv[j, :R], r_ix, int(dot_n[j]),
                              store.replica_ids),
@@ -333,14 +360,25 @@ class ReplicaNode:
             key, value, CausalContext.coerce(context), client_id=client_id,
             client_counter=client_counter, wall_time=wall_time)
 
-    def coordinate_updates(self, batch: UpdateBatch, *,
-                           client_id: str = "?", client_counter: int = 0,
-                           mask_fn=None) -> List[Version]:
-        """Batched multi-key coordination.  The packed backend takes the
-        one-scatter vectorized path; the object backend (the conformance
-        reference, and any non-DVV mechanism) degrades to a loop."""
+    def stage_updates(self, batch: UpdateBatch) -> List[StagedShard]:
+        """Batched multi-key coordination, first half (``coordinate_
+        many``): the packed backend stages one grouped tensor per shard
+        touched; the object backend stages nothing."""
         if isinstance(self.backend, PackedBackend):
-            return self.backend.coordinate_updates(batch, mask_fn=mask_fn)
+            return self.backend.stage_updates(batch)
+        return []
+
+    def commit_updates(self, batch: UpdateBatch,
+                       staged: Sequence[StagedShard],
+                       masks: Sequence[np.ndarray], *,
+                       client_id: str = "?", client_counter: int = 0
+                       ) -> List[Version]:
+        """The second half: the packed backend writes its staged shards
+        back under their masks; the object backend (the conformance
+        reference, and any non-DVV mechanism) runs its per-key loop.
+        Results in batch order."""
+        if isinstance(self.backend, PackedBackend):
+            return self.backend.commit_updates(batch, staged, masks)
         return [
             self.backend.coordinate_update(
                 key, value, ctx, client_id=client_id,
@@ -370,3 +408,30 @@ class ReplicaNode:
     def max_wall(self) -> float:
         """High-water mark of the node's wall column (geo frontier input)."""
         return self.backend.max_wall
+
+
+def coordinate_many(batches: Sequence[Tuple[ReplicaNode, UpdateBatch]], *,
+                    client_id: str = "?", client_counter: int = 0,
+                    mask_fn=None) -> List[List[Version]]:
+    """Batched multi-key coordination at several distinct nodes: every
+    node's batch is staged, the survival masks of all staged tensors come
+    from one ``sync_masks`` call (on a device ``mask_fn``, shared launches
+    of up to ``STACK_ROWS`` keys instead of one per node and shard), then
+    each node commits in the order given.
+
+    Exact because the stores staged are distinct: each node's shards are
+    separate stores and the nodes are distinct, so no commit changes a
+    store another batch staged.  Returns each batch's versions, in batch
+    order."""
+    if len({id(node) for node, _ in batches}) != len(batches):
+        raise ValueError("coordinate_many needs distinct nodes")
+    staged = [node.stage_updates(batch) for node, batch in batches]
+    masks = sync_masks([sh.payload.tensor for shards in staged
+                        for sh in shards], mask_fn)
+    out, at = [], 0
+    for (node, batch), shards in zip(batches, staged):
+        out.append(node.commit_updates(
+            batch, shards, masks[at: at + len(shards)],
+            client_id=client_id, client_counter=client_counter))
+        at += len(shards)
+    return out

@@ -60,6 +60,10 @@ PLANE_GET_REPAIR = "plane.get.repair"
 PLANE_PUT_ADMIT = "plane.put.admit"
 PLANE_PUT_UPDATE = "plane.put.update"
 PLANE_PUT_REPLICATE = "plane.put.replicate"
+PLANE_STACK_TENSORS = "plane.stack.tensors"
+PLANE_STACK_LAUNCHES = "plane.stack.launches"
+PLANE_STACK_CELLS = "plane.stack.cells"
+PLANE_STACK_LAUNCHED_CELLS = "plane.stack.launched_cells"
 PACKED_GATHER = "packed.gather"
 PACKED_MASK = "packed.mask"
 PACKED_CEILING = "packed.ceiling"
@@ -93,6 +97,10 @@ NAMES: Dict[str, Tuple[str, str, str]] = {
     PLANE_PUT_ADMIT: ("span", "cluster plane", _TRACE_ONLY),
     PLANE_PUT_UPDATE: ("span", "cluster plane", _TRACE_ONLY),
     PLANE_PUT_REPLICATE: ("span", "cluster plane", _TRACE_ONLY),
+    PLANE_STACK_TENSORS: ("counter", "cluster plane", "tensors_per_launch"),
+    PLANE_STACK_LAUNCHES: ("counter", "cluster plane", "tensors_per_launch"),
+    PLANE_STACK_CELLS: ("counter", "cluster plane", _TRACE_ONLY),
+    PLANE_STACK_LAUNCHED_CELLS: ("counter", "cluster plane", _TRACE_ONLY),
     PACKED_GATHER: ("span", "packed store", "gather_ms_per_op"),
     PACKED_MASK: ("span", "packed store", _TRACE_ONLY),
     PACKED_CEILING: ("span", "packed store", _TRACE_ONLY),

@@ -314,3 +314,136 @@ try:
         _assert_equal(c_packed, c_obj, (seed, grow))
 except ImportError:     # deterministic seeds above still run
     pass
+
+
+# ---------------------------------------------------------------------------
+# Stacked kernel launches: one device call per 32 keys of a plane call.
+# ---------------------------------------------------------------------------
+
+NODES5 = ("a", "b", "c", "d", "e")
+
+
+def _sharded(seed: int = 3, n_keys: int = 40):
+    """Five nodes, replication 3, eight shards; every key loaded, some
+    with concurrent siblings left by coordinators outside its replica set,
+    so write batches meet resident rows to keep and to drop."""
+    rng = random.Random(seed)
+    c = KVCluster(NODES5, DVV_MECHANISM, replication=3, shards=8,
+                  network=SimNetwork(seed=seed))
+    keys = [f"p{i}" for i in range(n_keys)]
+    c.put_many({k: (f"base-{k}", None) for k in keys}, via="a")
+    for k in keys[::3]:
+        c.put(k, f"fork-{k}", via="a", coordinator=rng.choice(NODES5))
+    c.deliver_replication()
+    return c, keys
+
+
+def _store_state(st: PackedVersionStore):
+    n, R = st.n_slots, st.n_replicas
+    return (tuple(st.replica_ids), tuple(st.keys), n,
+            st.vv[:n, :R].tolist(), st.dot_id[:n].tolist(),
+            st.dot_n[:n].tolist(), st.key_ix[:n].tolist(),
+            st.valid[:n].tolist(), list(st.values[:n]), st.wall[:n].tolist(),
+            st.digest_root(), st.value_root())
+
+
+def _rmw_items(c: KVCluster, keys, tag: str):
+    """One write per key: odd keys with the context of a read, even keys
+    blind (they keep the forks as siblings)."""
+    ctx = c.get_many(keys[1::2], via="a")
+    return {k: (f"{tag}-{k}", ctx[k].context if k in ctx else None)
+            for k in keys}
+
+
+def _kernel_launches(kind: str) -> int:
+    import repro.kernels.dvv_ops as pkg
+    cache = getattr(pkg, f"dvv_{kind}_bucketed")
+    return cache.hits + cache.misses
+
+
+def test_put_many_kernel_stacked_equals_reference():
+    """put_many on the kernel mints and stages every (coordinator, shard)
+    batch, masks them in shared launches and only then replicates: every
+    store and the network's queue, in order, must come out as on the numpy
+    plane."""
+    ker, keys = _sharded()
+    ref, _ = _sharded()
+    acks_k = ker.put_many(_rmw_items(ker, keys, "x"), via="a",
+                          use_kernel=True)
+    acks_r = ref.put_many(_rmw_items(ref, keys, "x"), via="a")
+    assert len({a.coordinator for a in acks_k.values()}) >= 2
+    assert acks_k == acks_r
+    assert list(ker.network.queue) == list(ref.network.queue)
+    for n in NODES5:
+        for st_k, st_r in zip(ker.nodes[n].shard_stores,
+                              ref.nodes[n].shard_stores):
+            assert _store_state(st_k) == _store_state(st_r), n
+            assert st_k.check_digests()
+
+
+@pytest.mark.parametrize("n", [1, 31, 32, 33, 65])
+@pytest.mark.parametrize("op", ["get", "put"])
+def test_plane_call_makes_one_launch_per_32_keys(op, n):
+    """A get_many/put_many of n keys on the kernel makes ceil(n/32)
+    launches, however many shards and quorum groups the keys span."""
+    c, keys = _sharded(n_keys=n)
+    kind = "read_sweep" if op == "get" else "sync_mask"
+    before = _kernel_launches(kind)
+    if op == "get":
+        c.get_many(keys, via="a", use_kernel=True)
+    else:
+        c.put_many(_rmw_items(c, keys, "y"), via="a", use_kernel=True)
+    assert _kernel_launches(kind) - before == -(-n // 32)
+
+
+def test_delta_round_keeps_one_launch_per_shard_pair():
+    """Delta anti-entropy rounds do not stack: each shard pair with a
+    payload still makes its own sync-mask launch."""
+    c, keys = _sharded()
+    c.network.partition({"a", "b"}, {"c", "d", "e"})
+    c.put_many({k: (f"z-{k}", None) for k in keys}, via="c")
+    c.network.heal()
+    c.network.queue.clear()
+    before = _kernel_launches("sync_mask")
+    stats = c.delta_antientropy_round(use_kernel=True)
+    pairs = sum(1 for st in stats for p in st.per_shard if p.payload_slots)
+    assert pairs > 1
+    assert _kernel_launches("sync_mask") - before == pairs
+
+
+def test_stacked_launches_equal_one_call_per_tensor():
+    """The stacking helper against one call per tensor, on random tensors
+    of different N, K and R (chunks that split tensors and mix widths)."""
+    from repro.store.packed import stacked_launches
+
+    rng = np.random.default_rng(5)
+    tensors = []
+    for n, k, r in [(3, 2, 3), (40, 5, 4), (0, 1, 2), (7, 1, 6), (29, 3, 2)]:
+        vvs = rng.integers(0, 4, (n, k, r)).astype(np.int32)
+        dids = rng.integers(-1, r, (n, k)).astype(np.int32)
+        has = dids != B.NO_DOT
+        dns = np.where(has, np.take_along_axis(
+            vvs, np.clip(dids, 0, None)[..., None], axis=-1)[..., 0] + 1,
+            0).astype(np.int32)
+        tensors.append((vvs, dids, dns, rng.random((n, k)) < 0.8))
+    calls = []
+
+    def sweep(vvs, dids, dns, valid):
+        calls.append(vvs.shape)
+        mask = B.sync_mask_np(vvs, dids, dns, valid)
+        ceil = np.stack([B.grouped_ceiling_np(
+            vvs[i][mask[i]], dids[i][mask[i]], dns[i][mask[i]],
+            np.zeros(int(mask[i].sum()), np.int64), 1)[0]
+            for i in range(len(vvs))]) if len(vvs) else \
+            np.zeros((0, vvs.shape[2]), np.int64)
+        return mask, ceil
+
+    got = stacked_launches(sweep, tensors, ceilings=True)
+    assert [s[0] for s in calls] == [32, 32, 15]
+    assert all(s[0] <= 32 for s in calls)
+    masks = stacked_launches(lambda *a: sweep(*a)[0], tensors)
+    for t, (mask, ceil), m in zip(tensors, got, masks):
+        want_mask, want_ceil = sweep(*t)
+        assert np.array_equal(mask, want_mask)
+        assert np.array_equal(m, want_mask)
+        assert np.array_equal(ceil, want_ceil)

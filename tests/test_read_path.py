@@ -339,6 +339,73 @@ def test_merged_read_stale_signal():
 
 
 # ---------------------------------------------------------------------------
+# Stacked launches: every quorum group of a get_many in shared kernel calls.
+# ---------------------------------------------------------------------------
+
+NODES5 = ("a", "b", "c", "d", "e")
+
+
+def _sharded_diverged(n_keys: int, seed: int = 4) -> KVCluster:
+    """Five nodes, replication 3, eight shards (so eight placement slices
+    and per-(node, shard) stores): every key is written, then rewritten
+    without context by coordinators inside and outside its replica set
+    (siblings, and union universes of 3 to 5 replicas), and half the
+    replication is dropped (stale members).  Same seed, same cluster."""
+    rng = random.Random(seed)
+    c = _cluster(seed=seed, nodes=NODES5, replication=3, shards=8)
+    keys = [f"s{i}" for i in range(n_keys)]
+    c.put_many({k: (f"base-{k}", None) for k in keys}, via="a")
+    c.deliver_replication()
+    for i, k in enumerate(keys):
+        for j in range(rng.randrange(3)):
+            c.put(k, f"w{j}-{k}", via="a",
+                  coordinator=rng.choice(NODES5[: 2 + i % 4]))
+    c.deliver_replication(max_messages=c.network.pending() // 2)
+    c.network.queue.clear()
+    return c
+
+
+@pytest.mark.parametrize("n_keys", [31, 32, 33, 65])
+def test_get_many_kernel_stacked_equals_reference(n_keys):
+    """A get_many on the kernel stacks every quorum group's tensor into
+    launches of up to 32 keys; it must read, flag staleness and push
+    repairs exactly as the numpy plane and the looped get do."""
+    from repro.kernels.dvv_ops import dvv_read_sweep_bucketed
+
+    ker, ref = _sharded_diverged(n_keys), _sharded_diverged(n_keys)
+    keys = [f"s{i}" for i in range(n_keys)]
+    quorum_sets = {ker.replicas_for(k) for k in keys}
+    assert len(quorum_sets) >= 3
+    looped = {k: ref.get(k, via="a", quorum=3) for k in keys}
+
+    stores_by_key = {k: [ker.nodes[r].store_for(k)
+                         for r in ker._reachable_replicas("a", k)[:3]]
+                     for k in keys}
+    want = quorum_merge_many(stores_by_key, keys)
+    got = quorum_merge_many(stores_by_key, keys,
+                            sweep_fn=dvv_read_sweep_bucketed)
+    assert len({len(m.replica_ids) for m in want.values()}) >= 2
+    assert any(m.stale for m in want.values())
+    for k in keys:
+        w, g = want[k], got[k]
+        assert (g.replica_ids, g.values, g.walls, g.clock_keys, g.entries,
+                g.stale) == (w.replica_ids, w.values, w.walls,
+                             w.clock_keys, w.entries, w.stale), k
+        for a in ("vv", "dot_id", "dot_n"):
+            assert np.array_equal(getattr(g, a), getattr(w, a)), (k, a)
+
+    got_read = ker.get_many(keys, via="a", quorum=3, repair=True,
+                            use_kernel=True)
+    ref_read = ref.get_many(keys, via="a", quorum=3, repair=True)
+    assert got_read == ref_read == looped
+    assert ker.network.pending() > 0
+    assert list(ker.network.queue) == list(ref.network.queue)
+    for n in NODES5:
+        for k in keys:
+            assert ker.nodes[n].versions(k) == ref.nodes[n].versions(k)
+
+
+# ---------------------------------------------------------------------------
 # dvv_read_sweep: fused survival + ceiling equals the numpy reference.
 # ---------------------------------------------------------------------------
 

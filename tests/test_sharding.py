@@ -278,9 +278,9 @@ def test_put_many_admission_atomic_across_shards(monkeypatch):
     owners = {k: c.replicas_for(k)[0] for k in keys}
     assert {"n0"} < set(owners.values())
     writes = []
-    real = ReplicaNode.coordinate_updates
+    real = ReplicaNode.commit_updates
     monkeypatch.setattr(
-        ReplicaNode, "coordinate_updates",
+        ReplicaNode, "commit_updates",
         lambda self, *a, **kw: writes.append(1) or real(self, *a, **kw))
     c.network.partition({"n0"}, {"n1", "n2"})
     with pytest.raises(Unavailable):

@@ -145,6 +145,33 @@ def test_kernel_front_spans(table):
     assert (cache.hits, cache.misses) == (1, 1)
 
 
+def test_stack_counters_and_their_reader(table):
+    """The cluster plane counts the tensors it stacks and the launches it
+    makes, only while on; ``tensors_per_launch`` reads their ratio and
+    gives nothing without them."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "tensors_per_launch", BENCH / "metrics" / "tensors_per_launch.py")
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    c = KVCluster(("a", "b", "c", "d", "e"), DVV_MECHANISM, replication=3,
+                  shards=8, network=SimNetwork(seed=1), read_quorum=2,
+                  write_quorum=2, seed=1)
+    keys = [f"k{i}" for i in range(40)]
+    c.put_many({k: (k, None) for k in keys}, via="a", use_kernel=True)
+    assert trace.snapshot()["counters"] == {}
+    assert reader.read({}) is None
+    trace.enable()
+    c.get_many(keys, via="a", use_kernel=True)
+    counters = trace.snapshot()["counters"]
+    assert counters[trace.PLANE_STACK_LAUNCHES] == 2
+    assert counters[trace.PLANE_STACK_TENSORS] >= 8   # one per shard group
+    # stacking pads each launch to its largest K and R, never below
+    assert counters[trace.PLANE_STACK_LAUNCHED_CELLS] >= \
+        counters[trace.PLANE_STACK_CELLS] > 0
+    assert reader.read({}) == counters[trace.PLANE_STACK_TENSORS] / 2
+
+
 def test_profile_turns_spans_on_and_tags_the_flush(table, tmp_path):
     assert not trace.active()
     opts = jax.profiler.ProfileOptions()
