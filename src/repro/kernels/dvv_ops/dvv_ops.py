@@ -73,11 +73,6 @@ def _leq_kernel(vx_ref, ix_ref, nx_ref, vy_ref, iy_ref, ny_ref, out_ref):
     out_ref[...] = jnp.where(ok, 1, 0)
 
 
-def _rot(a, d):
-    """Rotate the slot (leading) axis: ``_rot(a, d)[k] = a[(k + d) % K]``."""
-    return jnp.concatenate([a[d:], a[:d]], axis=0) if d else a
-
-
 def _sync_mask_kernel(vv_ref, id_ref, n_ref, valid_ref, out_ref):
     """Fused pairwise dominance + survival for one block of keys.
 
@@ -85,25 +80,30 @@ def _sync_mask_kernel(vv_ref, id_ref, n_ref, valid_ref, out_ref):
     id/n/valid: int32[K, B]
     out_ref   : int32[K, B]  — survival mask
 
-    Slot x meets slot (x+d) mod K for every static shift d = 1..K−1, all K
-    slots at once, so the body is K−1 batched ``_covers`` calls (code size
-    linear in K; work is still the K(K−1) pairs).  Of two equal clocks the
-    lower slot survives.
+    A loop over the partner slot j: each step reads slot j's clock and
+    compares it with all K slots in both directions, so the live working
+    set is a few ``[K, R_pad, B]`` blocks whatever K is (work is still the
+    K² pairs).  Slot x dies to a valid partner j whose history covers its
+    own, strictly or, of two equal clocks, when j is the lower slot (so a
+    slot, equal to itself, never kills itself).
     """
     K, Rp, B = vv_ref.shape
     vv = vv_ref[...]
-    ids, ns, valid = (r[...].reshape(K, 1, B)
-                      for r in (id_ref, n_ref, valid_ref))
+    ids, ns = (r[...].reshape(K, 1, B) for r in (id_ref, n_ref))
     sub = jax.lax.broadcasted_iota(jnp.int32, (K, Rp, B), 1)
     slot = jax.lax.broadcasted_iota(jnp.int32, (K, 1, B), 0)
-    # le[d][x]: history(x) ⊆ history((x+d) mod K)
-    le = [None] + [_covers(sub, vv, ids, ns, _rot(vv, d), _rot(ids, d),
-                           _rot(ns, d)) for d in range(1, K)]
-    alive = valid != 0
-    for d in range(1, K):
-        ge = _rot(jnp.where(le[K - d], 1, 0), d) != 0    # (x+d) ⊆ x
-        wrapped = slot >= K - d                           # partner is lower
-        alive = alive & ~(le[d] & (~ge | wrapped) & (_rot(valid, d) != 0))
+
+    def kill(j, dead):
+        vj = vv_ref[pl.ds(j, 1)]                              # [1, Rp, B]
+        ij, nj, ok_j = (r[pl.ds(j, 1), :].reshape(1, 1, B)
+                        for r in (id_ref, n_ref, valid_ref))
+        le = _covers(sub, vv, ids, ns, vj, ij, nj)            # x ⊆ j
+        ge = _covers(sub, vj, ij, nj, vv, ids, ns)            # j ⊆ x
+        hit = le & (~ge | (slot > j)) & (ok_j != 0)
+        return dead | jnp.where(hit, 1, 0)
+
+    dead = jax.lax.fori_loop(0, K, kill, jnp.zeros((K, 1, B), jnp.int32))
+    alive = (valid_ref[...].reshape(K, 1, B) != 0) & (dead == 0)
     out_ref[...] = jnp.where(alive, 1, 0).reshape(K, B)
 
 

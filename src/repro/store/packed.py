@@ -1340,8 +1340,9 @@ def stacked_launches(fn, tensors: Sequence[ClockTensor], *,
     ``sweep_fn`` (→ ``(mask, ceil [N, R])``).  Returns per tensor its mask
     ``[Ni, Ki]``, or ``(mask, ceil int64[Ni, Ri])`` pairs.  Counts
     ``plane.stack.tensors`` and ``plane.stack.launches`` while tracing,
-    and the clock cells (``N*K*R``) of the tensors given and of the
-    launches made, whose ratio is what the stacking pads.
+    the clock cells (``N*K*R``) of the tensors given and of the launches
+    made, whose ratio is what the stacking pads, and the clock-pair
+    comparisons (``N*K*K*R``) of the tensors given.
     """
     masks = [np.zeros(t[0].shape[:2], bool) for t in tensors]
     ceils = [np.zeros((t[0].shape[0], t[0].shape[2]), np.int64)
@@ -1381,6 +1382,10 @@ def stacked_launches(fn, tensors: Sequence[ClockTensor], *,
     trace.count(trace.PLANE_STACK_LAUNCHES, len(chunks))
     trace.count(trace.PLANE_STACK_CELLS, sum(t[0].size for t in tensors))
     trace.count(trace.PLANE_STACK_LAUNCHED_CELLS, launched)
+    if trace.active():
+        trace.count(trace.PLANE_STACK_COMPARISONS,
+                    sum(n * k * k * r for n, k, r in (t[0].shape
+                                                      for t in tensors)))
     return list(zip(masks, ceils)) if ceilings else masks
 
 

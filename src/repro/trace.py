@@ -64,6 +64,7 @@ PLANE_STACK_TENSORS = "plane.stack.tensors"
 PLANE_STACK_LAUNCHES = "plane.stack.launches"
 PLANE_STACK_CELLS = "plane.stack.cells"
 PLANE_STACK_LAUNCHED_CELLS = "plane.stack.launched_cells"
+PLANE_STACK_COMPARISONS = "plane.stack.comparisons"
 PACKED_GATHER = "packed.gather"
 PACKED_MASK = "packed.mask"
 PACKED_CEILING = "packed.ceiling"
@@ -101,6 +102,8 @@ NAMES: Dict[str, Tuple[str, str, str]] = {
     PLANE_STACK_LAUNCHES: ("counter", "cluster plane", "tensors_per_launch"),
     PLANE_STACK_CELLS: ("counter", "cluster plane", _TRACE_ONLY),
     PLANE_STACK_LAUNCHED_CELLS: ("counter", "cluster plane", _TRACE_ONLY),
+    PLANE_STACK_COMPARISONS: ("counter", "cluster plane",
+                              "stack_comparison_padding"),
     PACKED_GATHER: ("span", "packed store", "gather_ms_per_op"),
     PACKED_MASK: ("span", "packed store", _TRACE_ONLY),
     PACKED_CEILING: ("span", "packed store", _TRACE_ONLY),

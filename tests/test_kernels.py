@@ -96,6 +96,63 @@ def test_dvv_sync_mask_fused_kernel_sweep(n_replicas, n_keys, max_versions):
     np.testing.assert_array_equal(got, np_ref)
 
 
+def _wide_clock_sets(seed, n, k, r):
+    """Seeded ``[n, k, r]`` clock sets with every kind of slot: fresh
+    clocks (a dot on half of them), exact copies of an earlier slot,
+    clocks dominated by an earlier slot (lowered ranges, no dot), and
+    invalid slots that still hold clocks."""
+    rng = np.random.default_rng(seed)
+    vvs = np.zeros((n, k, r), np.int32)
+    dids = np.full((n, k), B.NO_DOT, np.int32)
+    dns = np.zeros((n, k), np.int32)
+    for i in range(n):
+        for j in range(k):
+            kind = rng.random() if j else 1.0
+            if kind < 0.25:                                 # equal
+                src = rng.integers(j)
+                vvs[i, j], dids[i, j], dns[i, j] = \
+                    vvs[i, src], dids[i, src], dns[i, src]
+            elif kind < 0.5:                                # dominated
+                src = rng.integers(j)
+                vvs[i, j] = rng.integers(0, vvs[i, src] + 1)
+            else:                                           # fresh
+                vvs[i, j] = rng.integers(0, 6, r)
+                if rng.random() < 0.5:
+                    dids[i, j] = rng.integers(r)
+                    dns[i, j] = vvs[i, j, dids[i, j]] + rng.integers(1, 4)
+    valid = rng.random((n, k)) < 0.85
+    return vvs, dids, dns, valid
+
+
+@pytest.mark.parametrize("kernel", ["sync_mask", "read_sweep"])
+@pytest.mark.parametrize("r", [3, 8])
+@pytest.mark.parametrize("k", [33, 64, 100, 128])
+def test_dvv_survival_kernel_wide_sets_equal_numpy(kernel, k, r):
+    """Past 32 slots a key (a hot cart's siblings, a quorum read's merged
+    versions) still gets exactly the numpy reference's survivors, and the
+    read sweep exactly the survivors' ceilings."""
+    from repro.kernels.dvv_ops.dvv_ops import dvv_read_sweep_pallas, \
+        dvv_sync_mask_pallas
+
+    n = 24
+    vvs, dids, dns, valid = _wide_clock_sets(k * 10 + r, n, k, r)
+    want = B.sync_mask_np(vvs, dids, dns, valid)
+    assert 0 < want.sum() < valid.sum()       # some slots are dominated
+    args = [jnp.asarray(a) for a in (vvs, dids, dns, valid)]
+    if kernel == "sync_mask":
+        got = np.asarray(dvv_sync_mask_pallas(*args, interpret=True))
+    else:
+        got, ceil = dvv_read_sweep_pallas(*args, interpret=True)
+        got, ceil = np.asarray(got), np.asarray(ceil)
+        rows = np.repeat(np.arange(n), k)[want.reshape(-1)]
+        want_ceil = B.grouped_ceiling_np(
+            vvs.reshape(-1, r)[want.reshape(-1)],
+            dids.reshape(-1)[want.reshape(-1)],
+            dns.reshape(-1)[want.reshape(-1)], rows, n)
+        np.testing.assert_array_equal(ceil, want_ceil)
+    np.testing.assert_array_equal(got, want)
+
+
 # ---------------------------------------------------------------------------
 # flash_attention
 # ---------------------------------------------------------------------------

@@ -88,7 +88,7 @@ def test_get_many_equals_looped_get(seed, packed):
 def test_get_many_kernel_mask_equals_reference():
     """use_kernel=True routes the stacked sweep through the shape-bucketed
     Pallas mask; results must not change.  A low-sibling cluster keeps the
-    interpret-mode kernel's K×K unroll cheap on the fast lane; the slow
+    interpret-mode kernel's K² work cheap on the fast lane; the slow
     lane (`make test-read` / nightly) sweeps deep sibling sets below."""
     c, keys = _diverged(packed=True, n_keys=8)
     ref = c.get_many(keys, via="a", quorum=3)
@@ -403,6 +403,68 @@ def test_get_many_kernel_stacked_equals_reference(n_keys):
     for n in NODES5:
         for k in keys:
             assert ker.nodes[n].versions(k) == ref.nodes[n].versions(k)
+
+
+def _hot_cart(seed: int = 5) -> KVCluster:
+    """A cart written blind 45 times by coordinators across its replica
+    set and beyond, with a third of the replication dropped: 40-odd
+    siblings on each replica, and replicas that disagree, so a quorum read
+    merges well over 32 versions.  Same seed, same cluster."""
+    rng = random.Random(seed)
+    c = _cluster(seed=seed, nodes=NODES5, replication=3, shards=8)
+    c.put_many({k: (f"base-{k}", None) for k in ("cart", "x1", "x2")},
+               via="a")
+    c.deliver_replication()
+    for i in range(45):
+        c.put("cart", f"add-{i}", via="a", coordinator=rng.choice(NODES5))
+    c.deliver_replication(max_messages=2 * c.network.pending() // 3)
+    c.network.queue.clear()
+    return c
+
+
+def test_hot_cart_past_32_siblings_kernel_equals_numpy(monkeypatch):
+    """A key with 40 and more siblings reads and writes through the
+    kernels (survival at K buckets of 64 and 128) exactly as through the
+    numpy plane: values, sibling sets, context bytes, acknowledged clocks
+    and every replica's versions."""
+    import repro.kernels.dvv_ops as pkg
+
+    ks = {"read": [], "write": []}
+    for kind, attr in (("read", "dvv_read_sweep_bucketed"),
+                       ("write", "dvv_sync_mask_bucketed")):
+        inner = getattr(pkg, attr)
+
+        def record(vvs, *rest, _inner=inner, _seen=ks[kind]):
+            _seen.append(vvs.shape[1])
+            return _inner(vvs, *rest)
+
+        monkeypatch.setattr(pkg, attr, record)
+
+    ker, ref = _hot_cart(), _hot_cart()
+    keys = ["cart", "x1", "x2"]
+    for quorum in (2, 3):
+        got = ker.get_many(keys, via="b", quorum=quorum, use_kernel=True)
+        want = ref.get_many(keys, via="b", quorum=quorum)
+        assert got == want
+        assert got["cart"].siblings >= 40
+        assert (got["cart"].context.to_bytes()
+                == want["cart"].context.to_bytes())
+    assert max(ks["read"]) > 64                  # the K = 128 bucket ran
+    # one blind add (every sibling survives it), then one with the read's
+    # context (it supersedes them all), each coordinated on the kernel
+    for ctx in (None, got["cart"].context):
+        items = {"cart": ("add-more", ctx), "x1": ("x1-new", None)}
+        acks_k = ker.put_many(items, via="b", quorum=2, use_kernel=True)
+        acks_r = ref.put_many(items, via="b", quorum=2)
+        assert acks_k == acks_r
+    assert max(ks["write"]) > 32                 # the K = 64 bucket ran
+    ker.deliver_replication()
+    ref.deliver_replication()
+    for n in NODES5:
+        for k in keys:
+            assert ker.nodes[n].versions(k) == ref.nodes[n].versions(k)
+    final = ker.get_many(keys, via="c", quorum=3, use_kernel=True)
+    assert final == ref.get_many(keys, via="c", quorum=3)
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,9 @@ Interpret mode (the CPU test path) cannot see what the TPU's kernel
 compiler refuses: unsupported ops, misaligned tiles, VMEM overuse.  These
 tests compile ``dvv_sync_mask_pallas`` and the read sweep for a described,
 not attached, ``v5e:2x2`` chip at shape buckets the store produces (K up to
-16, N up to 65,536), and check that the kernel is a ``tpu_custom_call``.
+256, the bucket of a quorum read over two replicas that each hold Riak's
+100-sibling maximum; N up to 65,536), and check that the kernel is a
+``tpu_custom_call``.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all
@@ -22,7 +24,8 @@ from repro.kernels.dvv_ops.dvv_ops import dvv_read_sweep_pallas, \
     dvv_sync_mask_pallas
 
 SHAPES = [(8, 2, 8), (1024, 4, 8), (4096, 8, 8), (4096, 16, 8),
-          (65536, 8, 8)]
+          (65536, 8, 8), (8, 32, 8), (8, 64, 8), (8, 128, 8), (8, 256, 8),
+          (32, 128, 8)]
 
 
 @pytest.fixture(scope="module")

@@ -169,6 +169,8 @@ def test_stack_counters_and_their_reader(table):
     # stacking pads each launch to its largest K and R, never below
     assert counters[trace.PLANE_STACK_LAUNCHED_CELLS] >= \
         counters[trace.PLANE_STACK_CELLS] > 0
+    # and the clock-pair comparisons of the tensors stacked
+    assert counters[trace.PLANE_STACK_COMPARISONS] > 0
     assert reader.read({}) == counters[trace.PLANE_STACK_TENSORS] / 2
 
 
