@@ -24,14 +24,15 @@ way in and once on the way out.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Dict, FrozenSet, Iterable, List, Mapping, Optional, \
     Tuple, Union
 
 import numpy as np
 
 from .. import trace
-from .packed import PackedPayload, PackedVersionStore, StoreDigest
+from .packed import PackedPayload, PackedVersionStore, StoreDigest, \
+    sync_masks
 from .replica import PackedBackend, ReplicaNode, _as_object_payload
 from .sharding import shard_of_key
 from .version import Version
@@ -121,11 +122,14 @@ def _object_payload_nbytes(payload: Dict[str, FrozenSet[Version]]) -> int:
         for k, vs in payload.items())
 
 
-def _store_delta_round(src_store: PackedVersionStore,
-                       dst_store: PackedVersionStore, *,
-                       mask_fn=None, max_ranges: Optional[int] = None,
-                       shard: int = -1) -> DeltaSyncStats:
-    """The two-phase round between two packed stores (one shard's plane)."""
+def _plan_store_round(src_store: PackedVersionStore,
+                      dst_store: PackedVersionStore, *,
+                      max_ranges: Optional[int] = None, shard: int = -1
+                      ) -> Tuple[Optional[PackedPayload], DeltaSyncStats]:
+    """Phase 1 and the slicing of the two-phase round between two packed
+    stores (one shard's plane): the payload for ``dst_store`` (``None``
+    when the stores agree) and the round's stats, ``changed`` left 0 for
+    the apply to fill in.  Writes nothing."""
     with trace.span(trace.AE_DIGEST):
         dst_digest = dst_store.sync_digest()
         ranked, width, n_divergent = delta_plan(src_store, dst_digest,
@@ -142,16 +146,43 @@ def _store_delta_round(src_store: PackedVersionStore,
         fallback = len(ranked) == 0 and \
             src_store.value_root() != dst_store.value_root()
     if len(ranked) == 0 and not fallback:
-        return DeltaSyncStats(width, 0, 0, 0, 0, digest_bytes, 0,
-                              shard=shard)
+        return None, DeltaSyncStats(width, 0, 0, 0, 0, digest_bytes, 0,
+                                    shard=shard)
     with trace.span(trace.AE_PAYLOAD):
         payload = src_store.payload() if fallback else \
             src_store.payload(key_ranges=ranked, ranges_width=width)
+    return payload, DeltaSyncStats(
+        width, 0 if fallback else n_divergent, len(ranked), len(payload),
+        payload.nbytes(), digest_bytes, 0, fallback=fallback, shard=shard)
+
+
+#: One shard's planned round: the receiving store, its payload (``None``
+#: when the stores agree) and the round's stats before the apply.
+PlannedRound = Tuple[PackedVersionStore, Optional[PackedPayload],
+                     DeltaSyncStats]
+
+
+def _apply_rounds(planned: List[PlannedRound],
+                  mask_fn=None) -> List[DeltaSyncStats]:
+    """The apply half of one push: stage every planned shard's payload
+    against its receiving store, take all their survival masks in one
+    ``sync_masks`` call (on a device ``mask_fn``, shared launches of up
+    to ``STACK_ROWS`` keys; a payload of more keys launches alone), then
+    commit in shard order.  Exact: every staged tensor belongs to a
+    different store, so no commit changes a store staged after it, and
+    commits (WAL appends, shadow-hook calls) keep the per-shard order.
+    Returns the stats with ``changed`` filled in, in ``planned`` order."""
+    if all(payload is None for _, payload, _ in planned):
+        return [stats for _, _, stats in planned]
     with trace.span(trace.AE_APPLY):
-        changed = dst_store.apply_payload(payload, mask_fn=mask_fn)
-    return DeltaSyncStats(width, 0 if fallback else n_divergent, len(ranked),
-                          len(payload), payload.nbytes(), digest_bytes,
-                          changed, fallback=fallback, shard=shard)
+        staged = [None if payload is None else dst.stage_payload(payload)
+                  for dst, payload, _ in planned]
+        masks = iter(sync_masks([st.tensor for st in staged
+                                 if st is not None], mask_fn,
+                                payloads=True))
+        return [stats if st is None else
+                replace(stats, changed=dst.commit_payload(st, next(masks)))
+                for (dst, _, stats), st in zip(planned, staged)]
 
 
 def _shard_budget(max_ranges: RangeBudget, shard: int) -> Optional[int]:
@@ -234,12 +265,16 @@ def delta_antientropy(src: ReplicaNode, dst: ReplicaNode, *,
     if sb.shards == 1:
         # Unsharded: the exact pre-sharding protocol (no root probe — the
         # tree diff's own root compare is the converged fast path).
-        return _store_delta_round(sb.stores[0], db.stores[0],
-                                  mask_fn=mask_fn,
-                                  max_ranges=_shard_budget(max_ranges, 0))
+        planned = [(db.stores[0], *_plan_store_round(
+            sb.stores[0], db.stores[0],
+            max_ranges=_shard_budget(max_ranges, 0)))]
+        return _apply_rounds(planned, mask_fn)[0]
     targets = range(sb.shards) if only_shards is None \
         else sorted(frozenset(only_shards))
-    per: List[DeltaSyncStats] = []
+    # Plan every shard's round, then apply them together: a shard's round
+    # reads and writes only its own pair of stores, so no plan depends on
+    # another shard's commit.
+    planned = []
     probe_bytes = 0
     src_stores, dst_stores = sb.stores, db.stores
     for s in targets:
@@ -249,10 +284,9 @@ def delta_antientropy(src: ReplicaNode, dst: ReplicaNode, *,
             # phase-0 skip: 8B digest root + 8B value root each direction
             probe_bytes += 32
             continue
-        per.append(_store_delta_round(
-            ss, ds, mask_fn=mask_fn,
-            max_ranges=_shard_budget(max_ranges, s), shard=s))
-    return _aggregate_stats(per, probe_bytes)
+        planned.append((ds, *_plan_store_round(
+            ss, ds, max_ranges=_shard_budget(max_ranges, s), shard=s)))
+    return _aggregate_stats(_apply_rounds(planned, mask_fn), probe_bytes)
 
 
 def bulk_receive_antientropy(node: ReplicaNode,

@@ -1322,7 +1322,8 @@ def _stack_rows(tensors: Sequence[ClockTensor],
 
 
 def stacked_launches(fn, tensors: Sequence[ClockTensor], *,
-                     ceilings: bool = False) -> List[Any]:
+                     ceilings: bool = False,
+                     payloads: bool = False) -> List[Any]:
     """Evaluate ``fn`` over independent grouped clock tensors in shared
     launches of at most ``STACK_ROWS`` keys.
 
@@ -1343,14 +1344,24 @@ def stacked_launches(fn, tensors: Sequence[ClockTensor], *,
     the clock cells (``N*K*R``) of the tensors given and of the launches
     made, whose ratio is what the stacking pads, and the clock-pair
     comparisons (``N*K*K*R``) of the tensors given.
+
+    ``payloads`` marks the staged payloads of one delta anti-entropy push:
+    a tensor of more than ``STACK_ROWS`` keys then launches alone and
+    whole, at its own bucket (as ``apply_payload`` launches it), so the
+    push never launches more often than once per payload, and only
+    ``ae.stack.payloads`` and ``ae.stack.launches`` are counted.
     """
     masks = [np.zeros(t[0].shape[:2], bool) for t in tensors]
     ceils = [np.zeros((t[0].shape[0], t[0].shape[2]), np.int64)
              for t in tensors] if ceilings else []
     chunks: List[List[Tuple[int, int, int]]] = []
+    alone: List[List[Tuple[int, int, int]]] = []
     room = 0
     for i, t in enumerate(tensors):
         n, lo = t[0].shape[0], 0
+        if payloads and n > B.STACK_ROWS:
+            alone.append([(i, 0, n)])
+            continue
         while lo < n:
             if not room:
                 chunks.append([])
@@ -1359,6 +1370,7 @@ def stacked_launches(fn, tensors: Sequence[ClockTensor], *,
             chunks[-1].append((i, lo, lo + take))
             room -= take
             lo += take
+    chunks += alone
     launched = 0
     for parts in chunks:
         i0, lo0, hi0 = parts[0]
@@ -1378,24 +1390,30 @@ def stacked_launches(fn, tensors: Sequence[ClockTensor], *,
             if ceilings:
                 ceils[i][lo:hi] = ceil[off: off + m, :r]
             off += m
-    trace.count(trace.PLANE_STACK_TENSORS, sum(1 for m in masks if len(m)))
-    trace.count(trace.PLANE_STACK_LAUNCHES, len(chunks))
-    trace.count(trace.PLANE_STACK_CELLS, sum(t[0].size for t in tensors))
-    trace.count(trace.PLANE_STACK_LAUNCHED_CELLS, launched)
-    if trace.active():
-        trace.count(trace.PLANE_STACK_COMPARISONS,
-                    sum(n * k * k * r for n, k, r in (t[0].shape
-                                                      for t in tensors)))
+    stacked = sum(1 for m in masks if len(m))
+    if payloads:
+        trace.count(trace.AE_STACK_PAYLOADS, stacked)
+        trace.count(trace.AE_STACK_LAUNCHES, len(chunks))
+    else:
+        trace.count(trace.PLANE_STACK_TENSORS, stacked)
+        trace.count(trace.PLANE_STACK_LAUNCHES, len(chunks))
+        trace.count(trace.PLANE_STACK_CELLS, sum(t[0].size for t in tensors))
+        trace.count(trace.PLANE_STACK_LAUNCHED_CELLS, launched)
+        if trace.active():
+            trace.count(trace.PLANE_STACK_COMPARISONS,
+                        sum(n * k * k * r for n, k, r in (t[0].shape
+                                                          for t in tensors)))
     return list(zip(masks, ceils)) if ceilings else masks
 
 
-def sync_masks(tensors: Sequence[ClockTensor], mask_fn=None
-               ) -> List[np.ndarray]:
+def sync_masks(tensors: Sequence[ClockTensor], mask_fn=None, *,
+               payloads: bool = False) -> List[np.ndarray]:
     """Survival masks of independent grouped clock tensors, aligned with
-    ``tensors``: on a device ``mask_fn`` in ``stacked_launches``, else the
-    numpy reference tensor by tensor."""
+    ``tensors``: on a device ``mask_fn`` in ``stacked_launches`` (with
+    ``payloads``, a delta push's rule), else the numpy reference tensor by
+    tensor."""
     if mask_fn is not None:
-        return stacked_launches(mask_fn, tensors)
+        return stacked_launches(mask_fn, tensors, payloads=payloads)
     with trace.span(trace.PACKED_MASK):
         return [B.sync_mask_np(*t) for t in tensors]
 

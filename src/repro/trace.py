@@ -79,6 +79,8 @@ NET_APPLY = "net.apply"
 AE_DIGEST = "ae.digest"
 AE_PAYLOAD = "ae.payload"
 AE_APPLY = "ae.apply"
+AE_STACK_PAYLOADS = "ae.stack.payloads"
+AE_STACK_LAUNCHES = "ae.stack.launches"
 
 _TRACE_ONLY = "trace only"
 #: name -> (kind, layer, per-layer metric that reads it).
@@ -119,6 +121,8 @@ NAMES: Dict[str, Tuple[str, str, str]] = {
     AE_DIGEST: ("span", "anti-entropy", "ae_digest_ms_per_repaired_key"),
     AE_PAYLOAD: ("span", "anti-entropy", _TRACE_ONLY),
     AE_APPLY: ("span", "anti-entropy", "ae_apply_ms_per_repaired_key"),
+    AE_STACK_PAYLOADS: ("counter", "anti-entropy", "ae_payloads_per_launch"),
+    AE_STACK_LAUNCHES: ("counter", "anti-entropy", "ae_payloads_per_launch"),
 }
 
 # -- state ------------------------------------------------------------------

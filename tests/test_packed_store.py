@@ -396,19 +396,184 @@ def test_plane_call_makes_one_launch_per_32_keys(op, n):
     assert _kernel_launches(kind) - before == -(-n // 32)
 
 
-def test_delta_round_keeps_one_launch_per_shard_pair():
-    """Delta anti-entropy rounds do not stack: each shard pair with a
-    payload still makes its own sync-mask launch."""
+def _stacked_count(sizes) -> int:
+    """Launches one push's staged tensors of ``sizes`` keys take: the keys
+    of tensors of at most ``STACK_ROWS`` share stacks, a larger tensor
+    launches alone."""
+    small = sum(n for n in sizes if n <= B.STACK_ROWS)
+    return -(-small // B.STACK_ROWS) + sum(
+        1 for n in sizes if n > B.STACK_ROWS)
+
+
+@pytest.fixture
+def pushes(monkeypatch):
+    """Per delta push of a cluster: the key counts of the tensors its
+    receiver staged and the sync-mask launches it made on the kernel."""
+    import repro.store.cluster as cluster_mod
+    out, sizes = [], []
+    stage = PackedVersionStore.stage_payload
+    real = cluster_mod._delta_antientropy
+
+    def staging(self, payload):
+        staged = stage(self, payload)
+        if staged is not None:
+            sizes.append(staged.tensor[0].shape[0])
+        return staged
+
+    def push(*args, **kw):
+        sizes.clear()
+        before = _kernel_launches("sync_mask")
+        stats = real(*args, **kw)
+        out.append((list(sizes), _kernel_launches("sync_mask") - before,
+                    stats))
+        return stats
+
+    monkeypatch.setattr(PackedVersionStore, "stage_payload", staging)
+    monkeypatch.setattr(cluster_mod, "_delta_antientropy", push)
+    return out
+
+
+def test_delta_round_stacks_shard_payloads_per_push(pushes):
+    """A delta push on the kernel stages every shard's payload, then masks
+    them in shared launches of up to 32 keys: fewer launches than shard
+    payloads, and the stores come out as on the numpy plane."""
     c, keys = _sharded()
-    c.network.partition({"a", "b"}, {"c", "d", "e"})
-    c.put_many({k: (f"z-{k}", None) for k in keys}, via="c")
-    c.network.heal()
-    c.network.queue.clear()
-    before = _kernel_launches("sync_mask")
+    ref, _ = _sharded()
+    for cl in (c, ref):
+        cl.network.partition({"a", "b"}, {"c", "d", "e"})
+        cl.put_many({k: (f"z-{k}", None) for k in keys}, via="c")
+        cl.network.heal()
+        cl.network.queue.clear()
     stats = c.delta_antientropy_round(use_kernel=True)
+    assert len(pushes) == len(stats)
+    for sizes, launches, st in pushes:
+        assert launches == _stacked_count(sizes)
+        assert launches <= sum(1 for p in st.per_shard if p.payload_slots)
     pairs = sum(1 for st in stats for p in st.per_shard if p.payload_slots)
     assert pairs > 1
-    assert _kernel_launches("sync_mask") - before == pairs
+    assert sum(launches for _, launches, _ in pushes) < pairs
+    del pushes[:]
+    assert ref.delta_antientropy_round() == stats
+    for n in NODES5:
+        for st_k, st_r in zip(c.nodes[n].shard_stores,
+                              ref.nodes[n].shard_stores):
+            assert _store_state(st_k) == _store_state(st_r), n
+
+
+def _per_shard_push(src, dst, mask_fn):
+    """The reference push: each shard's round planned and applied on its
+    own, one ``apply_payload`` (one mask call) per shard payload."""
+    from dataclasses import replace
+
+    from repro.store import bulk
+    per, probe = [], 0
+    for s, (ss, ds) in enumerate(zip(src.backend.stores,
+                                     dst.backend.stores)):
+        if ss.digest_root() == ds.digest_root() \
+                and ss.value_root() == ds.value_root():
+            probe += 32
+            continue
+        payload, stats = bulk._plan_store_round(ss, ds, shard=s)
+        if payload is not None:
+            stats = replace(stats, changed=ds.apply_payload(
+                payload, mask_fn=mask_fn))
+        per.append(stats)
+    return bulk._aggregate_stats(per, probe)
+
+
+def _partitioned_writes(c: KVCluster, rng: random.Random, keys) -> None:
+    """One step of a random schedule: a random split, writes on either
+    side (blind ones leave siblings), heal, replication dropped."""
+    nodes = list(NODES5)
+    rng.shuffle(nodes)
+    cut = rng.randint(1, 4)
+    c.network.partition(set(nodes[:cut]), set(nodes[cut:]))
+    for side in (nodes[:cut], nodes[cut:]):
+        chosen = rng.sample(keys, rng.randint(1, 48))
+        via = rng.choice(side)
+        try:
+            ctx = c.get_many(chosen[::2], via=via) if rng.random() < 0.5 \
+                else {}
+        except Unavailable:
+            ctx = {}
+        try:
+            c.put_many({k: (f"{via}-{rng.random():.6f}",
+                            ctx[k].context if k in ctx else None)
+                        for k in chosen}, via=via)
+        except Unavailable:
+            pass
+    c.network.heal()
+    c.network.queue.clear()
+
+
+@pytest.mark.parametrize("shards", [4, 8])
+@pytest.mark.parametrize("seed", [11, 12])
+def test_stacked_delta_push_equals_per_shard_apply(monkeypatch, seed,
+                                                   shards):
+    """Delta pushes with a recording numpy ``mask_fn`` standing in for the
+    kernel, and on the numpy plane, against the per-shard reference on a
+    twin cluster: every shard store slot for slot and every push's stats
+    (``per_shard`` too).  The first push ships a node's whole share of
+    600 keys, so some shard payloads are above ``STACK_ROWS`` keys: each
+    of those launches alone, at its own shape."""
+    import repro.kernels.dvv_ops as dvv_ops
+    from repro.store.bulk import delta_antientropy
+
+    calls = []
+
+    def recording(vvs, *rest):
+        calls.append(vvs.shape[0])
+        return B.sync_mask_np(vvs, *rest)
+
+    monkeypatch.setattr(dvv_ops, "dvv_sync_mask_bucketed", recording)
+
+    def build():
+        c = KVCluster(NODES5, DVV_MECHANISM, replication=3, shards=shards,
+                      network=SimNetwork(seed=seed))
+        keys = [f"q{i}" for i in range(600)]
+        c.network.partition({"e"}, set(NODES5) - {"e"})
+        c.put_many({k: (f"base-{k}", None) for k in keys}, via="a")
+        c.network.heal()
+        c.network.queue.clear()
+        return c, keys
+
+    (stk, keys), (ref, _) = build(), build()
+    large_seen, launches, payload_calls = False, 0, 0
+    for step in range(5):
+        if step:
+            _partitioned_writes(stk, random.Random(seed * 10 + step), keys)
+            _partitioned_writes(ref, random.Random(seed * 10 + step), keys)
+        on_kernel = step % 2 == 0
+        for a in NODES5:
+            for b in NODES5:
+                if a == b:
+                    continue
+                calls.clear()
+                got = delta_antientropy(stk.nodes[a], stk.nodes[b],
+                                        use_kernel=on_kernel)
+                sizes = list(calls)
+                if not on_kernel:
+                    assert sizes == []     # the numpy plane calls no kernel
+                want = _per_shard_push(ref.nodes[a], ref.nodes[b],
+                                       recording if on_kernel else None)
+                assert got == want, (step, a, b)
+                if not on_kernel:
+                    continue
+                ref_sizes = calls[len(sizes):]
+                assert len(sizes) == _stacked_count(ref_sizes)
+                assert len(sizes) <= len(ref_sizes)
+                launches += len(sizes)
+                payload_calls += len(ref_sizes)
+                large = [n for n in ref_sizes if n > B.STACK_ROWS]
+                assert sorted(n for n in sizes if n > B.STACK_ROWS) == \
+                    sorted(large)
+                large_seen |= bool(large)
+        for n in NODES5:
+            for st_s, st_r in zip(stk.nodes[n].shard_stores,
+                                  ref.nodes[n].shard_stores):
+                assert _store_state(st_s) == _store_state(st_r), (step, n)
+                assert st_s.check_digests()
+    assert large_seen and launches < payload_calls
 
 
 def test_stacked_launches_equal_one_call_per_tensor():
