@@ -13,29 +13,15 @@
 # suite (pinned conformance lanes + the slow hypothesis phase);
 # `test-durable` runs the segment-log durability suite (codec/segment
 # units, warm-restart conformance, the crash-point fuzz incl. its slow
-# every-extent sweep).
-# `bench-smoke` exercises the benchmark harness at toy
-# sizes; `bench-delta` runs the full divergence sweep and writes
-# BENCH_delta_sync.json; `bench-client` sweeps batched put_many/get_many vs
-# looped client calls and writes BENCH_client_api.json; `bench-read`
-# sweeps the one-sweep read plane (keys x divergence, repair on/off) and
-# writes BENCH_read_path.json; `bench-serving` runs the closed-loop
-# coalescing sweep and writes BENCH_serving.json; `bench-geo` runs the
-# geo tier sweep (snapshot latency, frontier staleness, WAN bytes) and
-# writes BENCH_geo.json; `bench-faults` runs the detection-latency and
-# flapping-wire-cost lanes and writes BENCH_faults.json; `bench-durable`
-# runs the warm-vs-cold recovery and log-overhead lanes and writes
-# BENCH_durable.json; `lint` is a
-# dependency-free syntax/bytecode pass (the container has no flake8/ruff
-# baked in).
+# every-extent sweep); `lint` is a dependency-free syntax/bytecode pass
+# (the container has no flake8/ruff baked in).  The benchmark lives in
+# `benchmarks/chip/` and runs on a TPU (README.md, "On a TPU").
 
 PY := python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
 .PHONY: test test-all test-property test-churn test-read test-shard \
-	test-serving test-geo test-faults test-durable bench-smoke bench \
-	bench-delta bench-client bench-churn bench-read bench-shard \
-	bench-serving bench-geo bench-faults bench-durable lint check
+	test-serving test-geo test-faults test-durable lint check
 
 test:
 	$(PY) -m pytest -x -q
@@ -67,60 +53,8 @@ test-faults:
 test-durable:
 	$(PY) -m pytest -q -m durable
 
-bench-smoke:
-	$(PY) -c "from benchmarks.kernel_bench import bulk_sync_rows; \
-	          print('\n'.join(bulk_sync_rows((256,), json_path=None, reps=1)))"
-	$(PY) -c "from benchmarks.delta_bench import delta_sync_rows; \
-	          print('\n'.join(delta_sync_rows((256,), (0.05,), \
-	          json_path=None, reps=1)))"
-	$(PY) -c "from benchmarks.client_bench import client_api_rows; \
-	          print('\n'.join(client_api_rows((64,), json_path=None, reps=1)))"
-	$(PY) -c "from benchmarks.read_bench import read_path_rows; \
-	          print('\n'.join(read_path_rows((64,), (0.1,), \
-	          json_path=None, reps=1)))"
-	$(PY) -c "from benchmarks.serving_bench import rows; \
-	          print('\n'.join(rows()))"
-	$(PY) -c "from benchmarks.geo_bench import rows; \
-	          print('\n'.join(rows()))"
-	$(PY) -c "from benchmarks.faults_bench import rows; \
-	          print('\n'.join(rows()))"
-	$(PY) -c "from benchmarks.durable_bench import rows; \
-	          print('\n'.join(rows()))"
-
-bench:
-	$(PY) -m benchmarks.run
-
-bench-delta:
-	$(PY) -c "from benchmarks.delta_bench import delta_sync_rows; \
-	          print('\n'.join(delta_sync_rows()))"
-
-bench-client:
-	$(PY) -m benchmarks.client_bench
-
-bench-churn:
-	$(PY) -c "from benchmarks.churn_bench import churn_rows; \
-	          print('\n'.join(churn_rows()))"
-
-bench-read:
-	$(PY) -m benchmarks.read_bench
-
-bench-shard:
-	$(PY) -m benchmarks.shard_bench
-
-bench-serving:
-	$(PY) -m benchmarks.serving_bench
-
-bench-geo:
-	$(PY) -m benchmarks.geo_bench
-
-bench-faults:
-	$(PY) -m benchmarks.faults_bench
-
-bench-durable:
-	$(PY) -m benchmarks.durable_bench
-
 lint:
-	$(PY) -m compileall -q src tests benchmarks examples
-	$(PY) -m pyflakes src tests benchmarks 2>/dev/null || true
+	$(PY) -m compileall -q src tests benchmarks/chip examples
+	$(PY) -m pyflakes src tests benchmarks/chip 2>/dev/null || true
 
 check: lint test

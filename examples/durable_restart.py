@@ -72,7 +72,7 @@ def main():
     print(f"  bootstrap_node after the fact: "
           f"{sum(s.payload_bytes + s.digest_bytes for s in cold):,}B "
           f"(mostly digests now — but an *empty* returnee pays the "
-          f"whole payload; see BENCH_durable.json)")
+          f"whole payload; tests/test_durable.py pins the ratio)")
 
     shutil.rmtree(tmp, ignore_errors=True)
 

@@ -1,10 +1,4 @@
-"""Distributed checkpointing with DVV-tracked manifests."""
-from .manager import CheckpointManager, RestoreResult
+"""Crash-safe file writes and DVV-tracked manifests for the segment log."""
 from .manifest import Manifest, ShardRecord, resolve_manifest_siblings
-from .shards import load_array, load_tree, save_array, save_tree
 
-__all__ = [
-    "CheckpointManager", "RestoreResult",
-    "Manifest", "ShardRecord", "resolve_manifest_siblings",
-    "save_array", "load_array", "save_tree", "load_tree",
-]
+__all__ = ["Manifest", "ShardRecord", "resolve_manifest_siblings"]

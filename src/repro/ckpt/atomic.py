@@ -1,13 +1,13 @@
 """Crash-safe file writes: write temp → fsync → atomic rename.
 
 The one torn-write discipline shared by everything that persists control
-state: checkpoint shard blobs (``ckpt/shards.py``), WAL manifests and
-packed-SoA snapshots (``store/wal.py``).  ``os.replace`` is atomic on
-POSIX, so a reader either sees the complete previous file or the complete
-new one — never a prefix.  The durability chain is: file bytes are fsynced
-before the rename (no rename-to-garbage), and the containing directory is
-fsynced after it (the rename itself survives a power cut), best-effort on
-platforms where directories cannot be opened.
+state: WAL manifests and packed-SoA snapshots (``store/wal.py``).
+``os.replace`` is atomic on POSIX, so a reader either sees the complete
+previous file or the complete new one — never a prefix.  The durability
+chain is: file bytes are fsynced before the rename (no rename-to-garbage),
+and the containing directory is fsynced after it (the rename itself
+survives a power cut), best-effort on platforms where directories cannot
+be opened.
 """
 from __future__ import annotations
 

@@ -1,16 +1,9 @@
-"""Pallas TPU kernels for the framework's compute hot-spots.
+"""Pallas TPU kernels for the store's clock algebra.
 
-Each kernel ships as <name>/<name>.py (pl.pallas_call + BlockSpec),
-<name>/ops.py (jit'd public wrapper, interpret-mode fallback off-TPU) and
-<name>/ref.py (pure-jnp oracle used by the sweep tests):
+The kernel ships as dvv_ops/dvv_ops.py (pl.pallas_call + BlockSpec),
+dvv_ops/ops.py (jit'd public wrapper, interpret-mode fallback off-TPU) and
+dvv_ops/ref.py (pure-jnp oracle used by the sweep tests):
 
-  * dvv_ops         — batched dotted-version-vector dominance (the paper's
-                      clock algebra, vectorized for anti-entropy sweeps)
-  * flash_attention — blockwise online-softmax attention (causal, sliding
-                      window, softcap, GQA)
-  * ssd_scan        — Mamba-2 SSD chunked scan (sequential chunk
-                      recurrence + intra-chunk quadratic form)
+  * dvv_ops — batched dotted-version-vector dominance (the paper's clock
+              algebra, vectorized for anti-entropy and read sweeps)
 """
-from . import dvv_ops, flash_attention, ssd_scan
-
-__all__ = ["dvv_ops", "flash_attention", "ssd_scan"]

@@ -1,5 +1,1 @@
-"""Launchers: mesh construction, sharding rules, dry-run, train/serve."""
-from .mesh import make_mesh, make_production_mesh
-from .sharding import Sharder
-
-__all__ = ["make_production_mesh", "make_mesh", "Sharder"]
+"""Launchers: the persistent compile cache and the store workload CLI."""

@@ -12,7 +12,7 @@ PUT:  forwarded to a coordinator that is a replica node for the key; the
       replication payload per destination.
 
 Failures, partitions and delayed replication all flow through ``SimNetwork``
-so tests and the training runtime can inject them deterministically.
+so tests and benchmarks can inject them deterministically.
 """
 from __future__ import annotations
 

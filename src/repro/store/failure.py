@@ -1,10 +1,9 @@
 """Self-driving membership: accrual failure detection wired into the loop.
 
-The seed's ``cluster/failure_detector.py`` was a training-sim helper that
-nothing in the store called — membership changes were hand-invoked, so the
-paper's "bounded by the degree of replication" claim only held while an
-operator watched the cluster.  This module promotes the detector to a
-first-class store citizen and closes the SWIM-style loop:
+With membership changes invoked by hand, the paper's "bounded by the
+degree of replication" claim only holds while an operator watches the
+cluster.  This module makes the failure detector a store citizen and
+closes the SWIM-style loop:
 
 * ``FailureDetector`` — per-node accrual suspicion.  A node's suspicion is
   its silence measured in *expected heartbeat intervals*; the expected

@@ -1,10 +1,8 @@
 """DVV-backed coordination services: the membership ledger and the
 work-stealing lease ledger, running *through* the replicated store.
 
-Promoted from the training-sim ``repro.cluster`` package (which keeps
-compat shims): both services are pure clients of the store's get/put
-surface and exist because their workloads are exactly the paper's
-motivating anomalies —
+Both services are pure clients of the store's get/put surface and exist
+because their workloads are exactly the paper's motivating anomalies —
 
 * **Membership** (``MembershipService``): ``node_id -> (status, epoch)``
   stored under one key.  Elastic scale-up/down means *concurrent*

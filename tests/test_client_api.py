@@ -214,14 +214,14 @@ def test_from_bytes_rejects_pickle_gadgets(tmp_path):
         CausalContext.from_bytes(evil_wire)
     assert not marker.exists()               # the gadget never executed
     # protocol-4 dotted STACK_GLOBAL through a repro module's own imports
-    # (repro.ckpt.shards does `import os`) must be rejected too — a
+    # (repro.store.wal does `import os`) must be rejected too — a
     # namespace-prefix allowance would resolve `os.system` through it
     def short_unicode(s):
         b = s.encode()
         return b"\x8c" + bytes([len(b)]) + b
 
     dotted = (b"\x80\x04"                                 # PROTO 4
-              + short_unicode("repro.ckpt.shards")
+              + short_unicode("repro.store.wal")
               + short_unicode("os.system")
               + b"\x93"                                   # STACK_GLOBAL
               + short_unicode(f"touch {marker}")

@@ -1,15 +1,12 @@
-"""Control-plane tests: membership, leases, elastic scaling, checkpoints —
-the framework-level payoff of DVV causality tracking."""
-import numpy as np
-import pytest
-
-from repro.cluster import (
-    ElasticController, FailureDetector, MembershipService, MemberView,
-    NodeStatus, WorkStealer,
+"""Control-plane tests: the store's membership, failure-detector and lease
+services, and manifest sibling resolution — coordination state that needs
+DVV causality tracking."""
+from repro.ckpt import Manifest, resolve_manifest_siblings
+from repro.core import ALL_MECHANISMS
+from repro.store import (
+    FailureDetector, KVCluster, MembershipService, MemberView, NodeStatus,
+    SimNetwork, WorkStealer,
 )
-from repro.ckpt import CheckpointManager, Manifest, resolve_manifest_siblings
-from repro.core import ALL_MECHANISMS, DVV_MECHANISM
-from repro.store import KVCluster, SimNetwork
 
 STORE_NODES = ("s1", "s2", "s3")
 
@@ -123,96 +120,8 @@ def test_steal_expired_lease():
 
 
 # ---------------------------------------------------------------------------
-# Elastic controller
+# Manifests
 # ---------------------------------------------------------------------------
-
-def test_elastic_plan_and_replan():
-    ctl = ElasticController([
-        ((2, 4), ("data", "model")),
-        ((1, 4), ("data", "model")),
-        ((1, 2), ("data", "model")),
-    ])
-    view = MemberView.from_dict(
-        {f"w{i}": (int(NodeStatus.ALIVE), 0) for i in range(8)})
-    plan = ctl.plan(view)
-    assert plan.mesh_shape == (2, 4) and plan.size == 8
-    # two nodes die -> shed data parallelism, keep model axis
-    d = view.to_dict()
-    d["w0"] = (int(NodeStatus.DEAD), 1)
-    d["w1"] = (int(NodeStatus.DEAD), 1)
-    new, changed = ctl.replan_on_failure(MemberView.from_dict(d), plan)
-    assert changed and new.mesh_shape == (1, 4)
-
-
-# ---------------------------------------------------------------------------
-# Checkpoints
-# ---------------------------------------------------------------------------
-
-def _arrays(step):
-    rng = np.random.default_rng(step)
-    return {"layer/w": rng.normal(size=(4, 4)).astype(np.float32),
-            "layer/b": rng.normal(size=(4,)).astype(np.float32)}
-
-
-def test_checkpoint_save_restore_roundtrip(tmp_path):
-    store = fresh_store(seed=4)
-    mgr = CheckpointManager(store, str(tmp_path), "run0", "s1")
-    arrays = _arrays(1)
-    mgr.save(1, arrays, data_cursor=100, rng_seed=7, rng_fold=1,
-             mesh_shape=(1, 1))
-    res = CheckpointManager(store, str(tmp_path), "run0", "s2").restore(via="s1")
-    assert res is not None and not res.had_conflict
-    assert res.manifest.step == 1 and res.manifest.data_cursor == 100
-    np.testing.assert_array_equal(res.arrays["layer/w"], arrays["layer/w"])
-
-
-def test_checkpoint_conflicting_lineages_resolved_identically(tmp_path):
-    """Partition → two coordinators finalize different step-2 manifests →
-    every node restores the SAME lineage after heal."""
-    store = fresh_store(seed=5)
-    net = store.network
-    m1 = CheckpointManager(store, str(tmp_path), "runX", "s1")
-    m1.save(1, _arrays(1), data_cursor=10, rng_seed=7, rng_fold=1,
-            mesh_shape=(1, 1), via="s1")
-    store.antientropy_round()
-    # both managers have read the step-1 manifest (shared causal context)
-    m2 = CheckpointManager(store, str(tmp_path), "runX", "s2")
-    assert m2.restore(via="s2").manifest.step == 1
-    net.partition({"s1"}, {"s2", "s3"})
-    m1.save(2, _arrays(21), data_cursor=20, rng_seed=7, rng_fold=2,
-            mesh_shape=(1, 1), via="s1")
-    m2.save(2, _arrays(22), data_cursor=21, rng_seed=7, rng_fold=2,
-            mesh_shape=(1, 1), via="s2")
-    net.heal()
-    store.antientropy_round()
-    r1 = CheckpointManager(store, str(tmp_path), "runX", "s1").restore(via="s1")
-    r2 = CheckpointManager(store, str(tmp_path), "runX", "s3").restore(via="s3")
-    assert r1.had_conflict  # the conflict was VISIBLE (not silent, unlike LWW)
-    assert r1.manifest.checksum() == r2.manifest.checksum()  # same resolution
-    np.testing.assert_array_equal(
-        r1.arrays["layer/w"], r2.arrays["layer/w"])
-    # after resolution the conflict is gone everywhere
-    store.antientropy_round()
-    r3 = CheckpointManager(store, str(tmp_path), "runX", "s2").restore(via="s2")
-    assert not r3.had_conflict
-
-
-def test_checkpoint_checksum_detects_corruption(tmp_path):
-    store = fresh_store(seed=6)
-    mgr = CheckpointManager(store, str(tmp_path), "runC", "s1")
-    manifest = mgr.save(1, _arrays(1), data_cursor=0, rng_seed=0, rng_fold=0,
-                        mesh_shape=(1,))
-    # corrupt a shard on disk
-    import os
-    target = os.path.join(str(tmp_path), manifest.shards[0].file)
-    data = np.load(target)
-    data.flat[0] += 1.0
-    with open(target, "wb") as f:
-        np.save(f, data)
-    store.deliver_replication()
-    with pytest.raises(IOError):
-        CheckpointManager(store, str(tmp_path), "runC", "s2").restore()
-
 
 def test_resolve_manifest_siblings_deterministic():
     a = Manifest("r", 5, (), 0, 0, 0, (1,), "s1")

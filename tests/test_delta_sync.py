@@ -21,7 +21,8 @@ from repro.core import DVV_MECHANISM
 from repro.core import batched as B
 from repro.kernels.dvv_ops import dvv_sync_mask_bucketed
 from repro.store import KVCluster, SimNetwork, Unavailable
-from repro.store.bulk import bulk_receive_antientropy, delta_antientropy
+from repro.store.bulk import bulk_receive_antientropy, delta_antientropy, \
+    delta_plan
 from repro.store.packed import PackedPayload, PackedVersionStore, key_bucket
 
 KEYS = tuple(f"k{i}" for i in range(6))
@@ -132,6 +133,71 @@ def test_delta_stats_accounting():
     # dst converged; a second round is a pure digest no-op
     st2 = c.delta_antientropy("a", "b")
     assert (st2.buckets_divergent, st2.payload_slots, st2.changed) == (0, 0, 0)
+
+
+def _bulk_store(n_keys, n_replicas=8, seed=0):
+    """A store of ``n_keys`` keys, one version each, from one payload."""
+    rng = np.random.default_rng(seed)
+    vv = rng.integers(0, 5, (n_keys, n_replicas)).astype(np.int32)
+    dot_id = rng.integers(0, n_replicas, n_keys).astype(np.int32)
+    dot_n = (vv[np.arange(n_keys), dot_id] + 1).astype(np.int32)
+    store = PackedVersionStore()
+    for i in range(n_replicas):
+        store.intern_replica(f"r{i}")
+    store.apply_payload(PackedPayload(
+        tuple(f"r{i}" for i in range(n_replicas)),
+        tuple(f"key{i}" for i in range(n_keys)), vv, dot_id, dot_n,
+        np.arange(n_keys, dtype=np.int32),
+        tuple(f"B{i}" for i in range(n_keys))))
+    return store
+
+
+def _advance(local, divergence, seed=1):
+    """A clone of ``local`` on which a ``divergence`` fraction of the keys
+    carries a newer version (each dominates the resident one)."""
+    rng = np.random.default_rng(seed)
+    remote = local.clone()
+    n_keys = len(local.keys)
+    n_div = max(1, int(round(n_keys * divergence)))
+    div = np.sort(rng.choice(n_keys, n_div, replace=False))
+    R = local.n_replicas
+    rows = np.flatnonzero(local.valid[: local.n_slots])
+    by_key = np.full(n_keys, -1, np.int64)
+    by_key[local.key_ix[rows]] = rows          # one live slot per key here
+    src = by_key[div]
+    vv = local.vv[src, :R].copy()
+    # fold the old dot in (n = m+1 is contiguous), then mint a fresh dot
+    vv[np.arange(n_div), local.dot_id[src]] = local.dot_n[src]
+    dot_id = rng.integers(0, R, n_div).astype(np.int32)
+    dot_n = (vv[np.arange(n_div), dot_id] + 1).astype(np.int32)
+    remote.apply_payload(PackedPayload(
+        tuple(local.replica_ids), tuple(local.keys[int(k)] for k in div),
+        vv, dot_id, dot_n, np.arange(n_div, dtype=np.int32),
+        tuple(f"D{int(k)}" for k in div)))
+    return remote, n_div
+
+
+@pytest.mark.parametrize("divergence", [0.001, 0.01, 0.1])
+def test_delta_payload_follows_divergence(divergence):
+    """On a fixed 10,000-key store a delta round costs O(divergence), not
+    O(store): its payload holds every divergent key and otherwise only the
+    keys sharing a divergent digest bucket — at most ``1 + 2 * keys per
+    bucket`` slots per divergent key — and one round converges the
+    receiver to what the full-payload round gives."""
+    local = _bulk_store(10_000)
+    remote, n_div = _advance(local, divergence)
+    ranked, width, _ = delta_plan(remote, local.sync_digest())
+    payload = remote.payload(key_ranges=ranked, ranges_width=width)
+    per_key = 1 + 2 * len(local.keys) / local.n_buckets
+    assert n_div <= len(payload) <= n_div * per_key, (n_div, len(payload))
+    full = remote.payload()
+    assert payload.nbytes() < full.nbytes() * divergence * per_key
+    delta_dst, full_dst = local.clone(), local.clone()
+    delta_dst.apply_payload(payload)
+    full_dst.apply_payload(full)
+    assert len(delta_dst.sync_digest().diff(remote.sync_digest())) == 0
+    assert delta_dst.digest_root() == full_dst.digest_root()
+    assert delta_dst.value_root() == full_dst.value_root()
 
 
 def test_capped_bidirectional_rounds_converge():

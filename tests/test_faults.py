@@ -28,7 +28,8 @@ import pytest
 
 from repro.core import DVV_MECHANISM
 from repro.store import (FailureDetector, GossipDriver, KVCluster,
-                         MembershipController, SimNetwork, cluster_converged)
+                         MembershipController, SimNetwork, Unavailable,
+                         cluster_converged)
 
 from test_churn import KEYS, _assert_backends_agree, _assert_replicas_agree, \
     _conformance, _run_schedule
@@ -132,6 +133,60 @@ def test_auto_evicts_failed_node_and_purges_queue(packed):
     # detection is bounded: dead_threshold intervals + one probe period
     bound = (mem.detector.dead_threshold + 2) * mem.period
     assert net.now <= 30.0 + 300.0 and bound < 300.0
+
+
+def _crash_under_writes(mode, seed=0):
+    """5 nodes, ``replication=3, write_quorum=3``; a write every 2 s for
+    900 s and ``n2`` crashes at 200 s.  ``mode``: ``none`` (nobody removes
+    it), ``manual`` (an operator oracle removes it the instant it dies) or
+    ``auto`` (the ``MembershipController`` evicts it).  Returns (writes
+    failed after the crash, eviction latency or None, the controller)."""
+    net = SimNetwork(seed=seed)
+    c = KVCluster(tuple(f"n{i}" for i in range(5)), DVV_MECHANISM,
+                  network=net, seed=seed, replication=3, write_quorum=3)
+    driver = GossipDriver(c, period=10.0, seed=seed)
+    mem = MembershipController(c, period=10.0, seed=seed, readmit=False) \
+        if mode == "auto" else None
+    rng = random.Random(seed * 13 + 7)
+    failed = 0
+    evicted_at = None
+    while net.now < 900.0:
+        driver.run_for(2.0)
+        if "n2" not in net.down and net.now >= 200.0 and \
+                "n2" in c.nodes:
+            net.fail_node("n2")
+            if mode == "manual":
+                c.remove_node("n2", handoff=True)
+        if evicted_at is None and "n2" not in c.nodes:
+            evicted_at = net.now
+        live = [n for n in c.nodes if n not in net.down]
+        try:
+            c.put(f"k{rng.randrange(24)}", f"v@{net.now:.0f}",
+                  via=live[rng.randrange(len(live))])
+        except Unavailable:
+            assert net.now >= 200.0          # no failure before the crash
+            failed += 1
+    latency = None if evicted_at is None else evicted_at - 200.0
+    return failed, latency, mem
+
+
+@pytest.mark.parametrize("mode", ["none", "manual", "auto"])
+def test_eviction_bounds_unavailability_after_a_crash(mode):
+    """Without eviction every write whose replica set holds the corpse
+    fails its quorum for the rest of the run; the operator oracle fails
+    none; the controller evicts within ``dead_threshold`` expected
+    heartbeat intervals of the crash and fails fewer writes than doing
+    nothing, all on simulated time."""
+    failed, latency, mem = _crash_under_writes(mode)
+    none_failed = _crash_under_writes("none")[0]
+    if mode == "none":
+        assert latency is None and failed > 0
+    elif mode == "manual":
+        assert latency == 0.0 and failed == 0
+    else:
+        assert latency is not None
+        assert latency <= mem.detector.dead_threshold * mem.period
+        assert failed < none_failed
 
 
 @pytest.mark.parametrize("packed", [True, False])

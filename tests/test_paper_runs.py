@@ -164,3 +164,44 @@ def test_dvv_semantics_examples():
     expected = CausalHistory.of(
         ("a", 1), ("a", 2), ("b", 1), ("c", 1), ("c", 2), ("c", 3), ("c", 7))
     assert c.to_history() == expected
+
+
+# ---------------------------------------------------------------------------
+# The canonical run through the replicated store, under each mechanism:
+# C1 and C2 write v and w blind at Rb; C3 writes x at Ra, reads it and
+# writes y; anti-entropy Rb -> Ra; C2 reads {v, w} at Rb and writes z at Ra.
+# ---------------------------------------------------------------------------
+
+# mechanism -> (z and y both survive at Ra?, v survives w's write at Rb?)
+CANONICAL_OUTCOME = {
+    "oracle": (True, True),
+    "dvv": (True, True),
+    "vv_client": (True, True),        # stateful clients: accurate (§3.3)
+    "vv_server": (False, False),      # Fig. 3: w overwrites v; z overwrites y
+    "wallclock_lww": (False, False),  # Fig. 2: total order, one survivor
+    "lamport": (False, False),        # §3.1: total order, one survivor
+}
+
+
+@pytest.mark.parametrize("mech", list(CANONICAL_OUTCOME))
+def test_canonical_run_through_the_store(mech):
+    from repro.core import ALL_MECHANISMS
+    from repro.store import KVCluster, SimNetwork
+
+    c = KVCluster(("a", "b"), ALL_MECHANISMS[mech], network=SimNetwork(seed=0))
+    c.put("k", "v", via="b", coordinator="b", client_id="C1",
+          client_counter=1, wall_time=1.0)
+    c.put("k", "w", via="b", coordinator="b", client_id="C2",
+          client_counter=1, wall_time=2.0)
+    c.put("k", "x", via="a", coordinator="a", client_id="C3",
+          client_counter=1, wall_time=3.0)
+    ctx = c.get("k", via="a").context
+    c.put("k", "y", context=ctx, via="a", coordinator="a", client_id="C3",
+          client_counter=2, wall_time=4.0)
+    c.antientropy("b", "a")
+    ctx_b = c.get("k", via="b").context
+    c.put("k", "z", context=ctx_b, via="a", coordinator="a", client_id="C2",
+          client_counter=2, wall_time=5.0)
+    zy_both = {"z", "y"} <= set(c.get("k", via="a").values)
+    v_survived = "v" in c.all_values("k")
+    assert (zy_both, v_survived) == CANONICAL_OUTCOME[mech]

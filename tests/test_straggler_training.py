@@ -1,11 +1,11 @@
-"""Straggler mitigation during data processing: work-stealing over the
-DVV lease ledger guarantees every shard is processed exactly once even
+"""Straggler mitigation through the store's services: work-stealing over
+the DVV lease ledger (``repro.store.WorkStealer``) with the store's
+``FailureDetector`` guarantees every shard is processed exactly once even
 when workers stall, die, or race through the same coordinator."""
 import random
 
-from repro.cluster import FailureDetector, WorkStealer
 from repro.core import DVV_MECHANISM
-from repro.store import KVCluster, SimNetwork
+from repro.store import FailureDetector, KVCluster, SimNetwork, WorkStealer
 
 STORE = ("s1", "s2", "s3")
 
