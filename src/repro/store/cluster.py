@@ -1006,16 +1006,23 @@ class KVCluster:
     def deliver_replication(self, max_messages: Optional[int] = None,
                             until: Optional[float] = None) -> int:
         """Flush queued coordinator→replica store messages (``until`` limits
-        delivery to messages due by that simulated time — the gossip
-        driver's per-tick drain)."""
-        def handler(msg):
-            kind, payload = msg.payload
-            assert kind == "store"
-            self.nodes[msg.dst].receive_antientropy(payload)
-            if self.geo is not None:
-                self.geo.note_receive(msg.dst, msg.payload)
-        return self.network.deliver(handler, until=until,
-                                    max_messages=max_messages)
+        delivery to messages due by that simulated time, as each gossip
+        tick's drain asks).  The pump is taken in one step
+        (``SimNetwork.take``) and each destination node applies its
+        messages together (``ReplicaNode.receive_pump``): one apply per
+        shard store on a packed node.  Returns the messages delivered."""
+        msgs = self.network.take(until=until, max_messages=max_messages)
+        with trace.span(trace.NET_APPLY):
+            by_dst: Dict[str, List[Any]] = {}
+            for msg in msgs:
+                kind, payload = msg.payload
+                assert kind == "store"
+                by_dst.setdefault(msg.dst, []).append(payload)
+                if self.geo is not None:
+                    self.geo.note_receive(msg.dst, msg.payload)
+            for dst, payloads in by_dst.items():
+                self.nodes[dst].receive_pump(payloads)
+        return len(msgs)
 
     def antientropy(self, src: str, dst: str,
                     keys: Optional[Sequence[str]] = None) -> None:

@@ -379,33 +379,42 @@ class SimNetwork:
                 self.wan_bytes += nbytes
         return True
 
-    def deliver(self, handler: Callable[[Message], None],
-                until: Optional[float] = None,
-                max_messages: Optional[int] = None) -> int:
-        """Deliver queued messages in timestamp order (stable, deterministic).
+    def take(self, until: Optional[float] = None,
+             max_messages: Optional[int] = None) -> List[Message]:
+        """Remove and return, in delivery order, the messages one delivery
+        pump hands over: those due by ``until`` (all when ``None``) whose
+        destination is reachable, sorted by ``(deliver_at, src, dst)``
+        (stable, deterministic) and cut at ``max_messages``.
 
         Messages to currently-unreachable destinations stay queued (they
         will flow once the partition heals — this models TCP retry /
-        hinted handoff).
+        hinted handoff).  ``now`` advances to the last message taken and
+        ``delivered`` counts each one.
         """
-        count = 0
-        while True:
-            with trace.span(trace.NET_DELIVER_SCAN):
-                ready = [m for m in self.queue
-                         if (until is None or m.deliver_at <= until)
-                         and self.reachable(m.src, m.dst)]
-                if not ready or (max_messages is not None
-                                 and count >= max_messages):
-                    break
-                ready.sort(key=lambda m: (m.deliver_at, m.src, m.dst))
-                msg = ready[0]
-                self.queue.remove(msg)
-            self.now = max(self.now, msg.deliver_at)
+        with trace.span(trace.NET_DELIVER_SCAN):
+            ready = [m for m in self.queue
+                     if (until is None or m.deliver_at <= until)
+                     and self.reachable(m.src, m.dst)]
+            ready.sort(key=lambda m: (m.deliver_at, m.src, m.dst))
+            if max_messages is not None:
+                ready = ready[: max(max_messages, 0)]
+            if ready:
+                taken = {id(m) for m in ready}
+                self.queue = [m for m in self.queue if id(m) not in taken]
+                self.now = max(self.now, ready[-1].deliver_at)
+                self.delivered += len(ready)
+        return ready
+
+    def deliver(self, handler: Callable[[Message], None],
+                until: Optional[float] = None,
+                max_messages: Optional[int] = None) -> int:
+        """Hand each message of one ``take`` to ``handler``, in delivery
+        order; messages the handler sends wait for a later call."""
+        msgs = self.take(until, max_messages)
+        for msg in msgs:
             with trace.span(trace.NET_APPLY):
                 handler(msg)
-            count += 1
-            self.delivered += 1
-        return count
+        return len(msgs)
 
     def pending(self) -> int:
         return len(self.queue)

@@ -290,6 +290,34 @@ def concat_payloads(payloads: Sequence[PackedPayload]) -> PackedPayload:
                          tuple(values), wall)
 
 
+def join_payloads(payloads: Sequence[PackedPayload]) -> PackedPayload:
+    """Join payloads bound for one store, whose key sets may overlap, into
+    one payload that applies as they do in turn (sync is a join).
+
+    Rows stay in the order given, under the union replica universe; each
+    key appears once in ``keys``, in first-appearance order.  A row whose
+    clock (vv, dot and counter) equals an earlier row of the same key is
+    dropped: the survival mask keeps the earliest of equal clocks
+    (``dup_earlier``), so that copy is the one an apply in turn keeps."""
+    if len(payloads) == 1:
+        return payloads[0]
+    p = concat_payloads(payloads)
+    index: Dict[str, int] = {}
+    kmap = np.asarray([index.setdefault(k, len(index)) for k in p.keys],
+                      np.int64)
+    kix = kmap[p.key_ix]
+    rows = np.column_stack([kix, p.vv, p.dot_id, p.dot_n])
+    # one opaque item per row: a 1-D unique, several times faster than
+    # ``np.unique(axis=0)`` at a pump's few rows
+    row_t = np.dtype((np.void, rows.dtype.itemsize * rows.shape[1]))
+    _, first = np.unique(rows.view(row_t).ravel(), return_index=True)
+    keep = np.sort(first)
+    return PackedPayload(
+        p.replica_ids, tuple(index), p.vv[keep], p.dot_id[keep],
+        p.dot_n[keep], kix[keep].astype(np.int32),
+        tuple(p.values[int(r)] for r in keep), p.wall[keep])
+
+
 def split_payload(payload: PackedPayload, shards: int
                   ) -> Dict[int, PackedPayload]:
     """Partition a payload by key shard (top bits of the stable 64-bit key

@@ -19,11 +19,13 @@ from typing import Any, Dict, FrozenSet, Iterable, List, NamedTuple, \
 
 import numpy as np
 
+from .. import trace
 from ..core import batched as B
 from ..core.kernel import Mechanism
 from .context import CausalContext
 from .packed import DIGEST_BUCKETS, PackedPayload, PackedVersionStore, \
-    StagedPayload, concat_payloads, split_payload, sync_masks
+    StagedPayload, concat_payloads, join_payloads, split_payload, \
+    sync_masks
 from .sharding import shard_of_key
 from .version import Version, clocks_of, sync_versions
 
@@ -259,6 +261,25 @@ class PackedBackend:
                 changed += 1
         return changed
 
+    def receive_pump(self, payloads: Sequence[PackedPayload]) -> None:
+        """Apply the packed payloads one delivery pump brought this node,
+        given in delivery order: each is split by shard once, each shard
+        store's parts are joined (``join_payloads``) and the store applies
+        the join once.  Exact because sync is a join and the stores are
+        distinct; every intermediate live set is skipped, so a store with
+        a ``shadow_hook`` must not come here (``ReplicaNode.
+        receive_pump``)."""
+        parts: Dict[int, List[PackedPayload]] = {}
+        n_parts = 0
+        for payload in payloads:
+            for s, part in split_payload(payload, self.shards).items():
+                parts.setdefault(s, []).append(part)
+                n_parts += 1
+        trace.count(trace.REPLICA_APPLY_PARTS, n_parts)
+        trace.count(trace.REPLICA_APPLY_STORES, len(parts))
+        for s, group in parts.items():
+            self.stores[s].apply_payload(join_payloads(group))
+
     def metadata_size(self, key: str) -> int:
         return self.store_for(key).metadata_size(key)
 
@@ -395,6 +416,22 @@ class ReplicaNode:
         if isinstance(self.backend, PackedBackend):
             return self.backend.receive_antientropy(payload, mask_fn=mask_fn)
         return self.backend.receive_antientropy(payload)
+
+    def receive_pump(self, payloads: Sequence[Payload]) -> None:
+        """Apply the payloads one delivery pump brought this node, in
+        delivery order.  A packed backend applies each shard store once
+        (``PackedBackend.receive_pump``).  Two cases apply message by
+        message: stores with a ``shadow_hook`` (the geo tier's stable
+        shadows must see every intermediate live set) and object (dict)
+        payloads."""
+        backend = self.backend
+        if (isinstance(backend, PackedBackend)
+                and backend.shadow_hook is None
+                and all(isinstance(p, PackedPayload) for p in payloads)):
+            backend.receive_pump(payloads)
+            return
+        for payload in payloads:
+            self.receive_antientropy(payload)
 
     # -- introspection -------------------------------------------------------------
     def metadata_size(self, key: str) -> int:

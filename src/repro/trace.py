@@ -81,6 +81,8 @@ AE_PAYLOAD = "ae.payload"
 AE_APPLY = "ae.apply"
 AE_STACK_PAYLOADS = "ae.stack.payloads"
 AE_STACK_LAUNCHES = "ae.stack.launches"
+REPLICA_APPLY_PARTS = "replica.apply.parts"
+REPLICA_APPLY_STORES = "replica.apply.stores"
 
 _TRACE_ONLY = "trace only"
 #: name -> (kind, layer, per-layer metric that reads it).
@@ -123,6 +125,10 @@ NAMES: Dict[str, Tuple[str, str, str]] = {
     AE_APPLY: ("span", "anti-entropy", "ae_apply_ms_per_repaired_key"),
     AE_STACK_PAYLOADS: ("counter", "anti-entropy", "ae_payloads_per_launch"),
     AE_STACK_LAUNCHES: ("counter", "anti-entropy", "ae_payloads_per_launch"),
+    REPLICA_APPLY_PARTS: ("counter", "cluster plane",
+                          "parts_per_store_apply"),
+    REPLICA_APPLY_STORES: ("counter", "cluster plane",
+                           "parts_per_store_apply"),
 }
 
 # -- state ------------------------------------------------------------------
